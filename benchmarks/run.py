@@ -42,7 +42,7 @@ from repro.core import (EmbeddingBagCollection, EmbeddingStageConfig,
                         plan_from_trace, unique_access_pct)
 from repro.data.pipeline import HETERO_MIXES
 from repro.models.dlrm import DLRM, DLRMConfig
-from repro.utils import timeit_median
+from repro.utils import enable_compile_cache, timeit_median
 
 from benchmarks.tpu_model import EmbedKernelModel
 
@@ -1437,6 +1437,7 @@ def main(argv: list[str] | None = None) -> None:
                          "reproduces the checked-in baseline exactly); "
                          "recorded at the top level of --json output")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     SEED = args.seed
     selected = (ALL if args.sweep is None
                 else [fn for fn in ALL if fn.__name__ in args.sweep])

@@ -3,9 +3,10 @@
 Owns a stack of homogeneous embedding tables [T, R, D] (heterogeneous sets are
 grouped into homogeneous collections by the DLRM model), the per-table
 hot-first plans (L2P analogue), and the kernel tuning knobs. Tables are
-processed with a single stacked lookup (vmapped kernel / gather), matching the
-paper's "each GPU executes one or more embedding tables serially" — the grid
-dimension over tables is the serialization.
+processed with a single stacked lookup (one kernel launch over the stack, or
+a vmapped gather), matching the paper's "each GPU executes one or more
+embedding tables serially" — the kernel's table grid axis is the
+serialization.
 
 Storage is pluggable: `EmbeddingStageConfig.storage` names a backend in the
 `repro.storage` registry (`device` — dense XLA/Pallas gather, seed
@@ -24,6 +25,7 @@ exercised in launch/steps.py as the optimized path).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -48,6 +50,24 @@ def _pool_rows_core(rows_t: jnp.ndarray, w_t: jnp.ndarray | None,
     if combine == "mean":
         pooled = pooled / pooling
     return pooled
+
+
+@functools.partial(jax.jit, static_argnames=("num_tables", "rows", "dim",
+                                             "dtype"))
+def _init_tables(rng: jax.Array, perm: jnp.ndarray | None, *,
+                 num_tables: int, rows: int, dim: int, dtype) -> jnp.ndarray:
+    """Random [T, R, D] tables, one table at a time (`lax.map` writes each
+    into the preallocated stack), so the device peak is the stack plus
+    one table — not the two or three whole stacks that an eager
+    normal-scale-permute chain holds at once. `perm` [T, R] stores each
+    table hot-first."""
+    scale = 1.0 / np.sqrt(dim)
+
+    def one(args):
+        key, p = args
+        t = jax.random.normal(key, (rows, dim), dtype) * scale
+        return t if p is None else jnp.take(t, p, axis=0)
+    return jax.lax.map(one, (jax.random.split(rng, num_tables), perm))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,13 +101,12 @@ class EmbeddingStageConfig:
     def table_bytes(self) -> int:
         return self.num_tables * self.rows * self.dim * self.jnp_dtype.itemsize
 
-    def kernel_opts(self, interpret: bool = False) -> EmbeddingBagOpts:
+    def kernel_opts(self) -> EmbeddingBagOpts:
         return EmbeddingBagOpts(
             prefetch_distance=self.prefetch_distance,
             batch_block=self.batch_block,
             num_hot=self.pinned_rows,
             mode=self.combine,
-            interpret=interpret,
         )
 
 
@@ -123,18 +142,16 @@ class EmbeddingBagCollection:
     # -- params -------------------------------------------------------------
     def init(self, rng: jax.Array) -> dict:
         cfg = self.cfg
-        scale = 1.0 / np.sqrt(cfg.dim)
-        tables = jax.random.normal(
-            rng, (cfg.num_tables + cfg.shard_pad_tables, cfg.rows, cfg.dim),
-            cfg.jnp_dtype) * scale
+        perm = None
         if cfg.pinned_rows > 0:
             # Store hot-first (offline, one-time — like the paper's pinning
             # kernel launched before the embedding bag kernel).
             perm = jnp.asarray(np.stack(
                 [p.perm for p in self.plans]
                 + [self.plans[0].perm] * cfg.shard_pad_tables))
-            tables = jax.vmap(lambda t, p: jnp.take(t, p, axis=0))(tables, perm)
-        return {"tables": tables}
+        return {"tables": _init_tables(
+            rng, perm, num_tables=cfg.num_tables + cfg.shard_pad_tables,
+            rows=cfg.rows, dim=cfg.dim, dtype=cfg.jnp_dtype)}
 
     def remap_indices(self, indices: jnp.ndarray) -> jnp.ndarray:
         """Raw row ids -> hot-first ids. indices: [B, T, L]."""

@@ -182,16 +182,12 @@ def estimate_device_budget(fraction: float = 0.5,
     `fallback_bytes` — None there means "no estimate", and callers (the
     serving auto-tuner) skip the capacity step rather than guessing.
     """
-    try:
-        import jax
-        dev = device if device is not None else jax.local_devices()[0]
-        stats = dev.memory_stats()
-        if stats and "bytes_limit" in stats:
-            free = int(stats["bytes_limit"]) - int(
-                stats.get("bytes_in_use", 0))
-            return int(max(0, free) * fraction)
-    except Exception:
-        pass
+    import jax
+    dev = device if device is not None else jax.local_devices()[0]
+    stats = dev.memory_stats()
+    if stats and "bytes_limit" in stats:
+        free = int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+        return int(max(0, free) * fraction)
     return fallback_bytes
 
 

@@ -1,6 +1,6 @@
-"""Fused warm-cache lookup kernel: hit-gather + pooled reduce + miss-list
-in ONE Pallas launch (ROADMAP item 2; paper §IV-B/§IV-C pushed into the
-kernel).
+"""Fused warm-cache lookup kernel: hit-gather + pooled reduce in ONE Pallas
+launch, plus the miss-list the host cold path consumes (paper §IV-B/§IV-C
+pushed into the kernel).
 
 The tiered parameter server used to resolve every index in Python tier
 logic: probe the warm tag store, read hit payloads back to the host, gather
@@ -14,27 +14,37 @@ trip with a single kernel launch over the device-resident cache payload
                               -1                    miss (zero contribution,
                                                     emitted on the miss-list)
                               < -1                  padding (zero contribution,
-                                                    NOT emitted — `_pad_batch`
-                                                    dummy bags)
+                                                    NOT on the miss-list —
+                                                    the wrapper's dummy bags)
                               [0, num_hot)          hot-block row (when `hot`
                                                     is passed)
                               [num_hot, num_hot+C)  cache slot + num_hot
-           rows  [B, L]   — raw row ids (only read for miss emission)
+           rows  [B, L]   — raw row ids (only read for the miss-list)
            weights [B, L] — optional per-lookup scales
            hot [K, D]     — optional VMEM-pinned hot block (L2-pin analogue)
   outputs  pooled [B, D]  — per-bag sum/mean with ZERO contribution at miss
-                            and pad positions
+                            and pad positions (the kernel's one output)
            miss_rows      — distinct missing raw row ids (sorted)
            miss_pos       — flat b*L+i occurrence positions (ascending)
 
+The miss-list is read off the host-built slot-map (`_miss_list_from_slots`,
+one `np.unique`) for every backend: the host already knows each MISS
+position when it builds the map, so the kernel does not emit it. An
+in-kernel list would need `B·L` words of SMEM (1.2 MB at the serving
+batch B=2048, L=150 — more than v5e's 1 MiB SMEM) and a per-miss scan of
+the distinct rows seen so far, quadratic in a cold batch's misses.
+
 Bit-exactness contract (float32, the serving dtype): `pooled` equals
 `ref.embedding_bag_ref` evaluated on a table whose missing rows are zeroed
-— at 100% residency that is the dense reference itself. Two empirically
-pinned-down rules make this hold (see tests/test_kernel_fused.py):
+— at 100% residency that is the dense reference itself. Four empirically
+pinned-down rules make this hold on the CPU (see tests/test_kernel_fused.py):
 
   * the reduction must be a vector reduce over a gathered [L, D] bag
     buffer (`jnp.sum(axis=0)`), never a sequential scalar accumulation —
     XLA's reduce orders differently and drifts by 1 ULP;
+  * weights scale each assembled row in place before the bag reduce
+    reads it — a multiply fused into the reduce contracts to an FMA and
+    drifts by 1 ULP;
   * mean-mode division happens only after the full numerator is assembled,
     and miss-containing bags are later RECOMPUTED whole (position order)
     by `complete_miss_bags`, never "completed" by adding cold rows to the
@@ -48,9 +58,7 @@ The kernel therefore assembles each grid step's bags into one flat
 [batch_block * L, D] VMEM buffer (cache rows via `pltpu.make_async_copy`
 row DMAs `prefetch_distance` deep, hot rows from VMEM, zeros at
 miss/pad positions) and reduces each bag with a single VPU `sum(axis=0)`.
-The miss-list lives in SMEM: a running (distinct, occurrence) counter pair
-persists across sequential grid steps, and a short scan over the
-already-emitted entries deduplicates distinct rows in-kernel.
+Grid steps share no state, so the batch axis is `parallel`.
 
 Backends mirror ops.py: 'pallas' (interpret=True automatically on CPU) for
 the TPU launch, 'xla' — an *eager* pure-jnp composition of exactly the
@@ -92,8 +100,8 @@ class FusedLookupOpts:
 
 @dataclasses.dataclass(frozen=True)
 class FusedLookupResult:
-    """pooled stays on device; the miss-list is host-side (its consumer is
-    the host cold path, so the wrapper trims + sorts it in numpy)."""
+    """pooled stays on device; the miss-list is host-side numpy (its
+    consumer is the host cold path)."""
 
     pooled: jnp.ndarray      # [B, D] table dtype
     miss_rows: np.ndarray    # [n_distinct] int32, sorted ascending
@@ -104,33 +112,22 @@ class FusedLookupResult:
         return self.miss_rows.size == 0
 
 
-def _fused_kernel(slot_ref, row_ref, w_ref, cache_ref, hot_ref,
-                  out_ref, mrow_ref, mpos_ref, mcnt_ref,
+def _fused_kernel(slot_ref, w_ref, cache_ref, hot_ref, out_ref,
                   buf_ref, sem_ref, *, pooling: int, distance: int,
                   num_hot: int, has_weights: bool):
     """One grid step: `batch_block` bags through the flat assembly buffer.
 
     slot_ref: SMEM [bb, L] int32 slot-map (scalar core: DMA addressing)
-    row_ref:  SMEM [bb, L] int32 raw ids (miss emission only)
-    w_ref:    VMEM [bb, L] f32 or None (vector math at the bag reduce)
+    w_ref:    SMEM [bb, L] f32 or None (one scalar per position)
     cache_ref: HBM [C, D] warm payload (memory_space=ANY; manual DMA only)
     hot_ref:  VMEM [K, D] or None
     out_ref:  VMEM [bb, D]
-    mrow_ref/mpos_ref: SMEM [cap] miss outputs (constant index map — the
-        same block revisits every step, so entries accumulate)
-    mcnt_ref: SMEM [2] running counters [n_distinct, n_occurrences]
     buf_ref:  VMEM scratch [bb * L, D] — the per-step assembly buffer
     sem_ref:  DMA semaphores [distance]
     """
     bb = out_ref.shape[0]
     total = bb * pooling
     f32 = jnp.float32
-    blk = pl.program_id(0)
-
-    @pl.when(blk == 0)
-    def _():
-        mcnt_ref[0] = 0
-        mcnt_ref[1] = 0
 
     def start_fetch(t):
         """Begin the cache-row DMA for flat step t (warm slots only)."""
@@ -164,31 +161,20 @@ def _fused_kernel(slot_ref, row_ref, w_ref, cache_ref, hot_ref,
             @pl.when(jnp.logical_and(slot >= 0, slot < num_hot))
             def _():
                 safe = jnp.minimum(slot, num_hot - 1)
-                pl.store(buf_ref, (pl.ds(t, 1), slice(None)),
-                         pl.load(hot_ref, (pl.ds(safe, 1), slice(None))))
+                buf_ref[pl.ds(t, 1), :] = hot_ref[pl.ds(safe, 1), :]
 
         @pl.when(slot < 0)
         def _():
-            pl.store(buf_ref, (pl.ds(t, 1), slice(None)),
-                     jnp.zeros((1, buf_ref.shape[1]), buf_ref.dtype))
+            buf_ref[pl.ds(t, 1), :] = jnp.zeros((1, buf_ref.shape[1]),
+                                                buf_ref.dtype)
 
-        # Miss emission (slot == MISS only; PAD bags stay silent).
-        @pl.when(slot == MISS)
-        def _():
-            row = row_ref[s, i]
-            occ = mcnt_ref[1]
-            mpos_ref[occ] = blk * total + t
-            mcnt_ref[1] = occ + 1
-            nd = mcnt_ref[0]
-            seen = jax.lax.fori_loop(
-                0, nd,
-                lambda j, f: jnp.logical_or(f, mrow_ref[j] == row),
-                jnp.bool_(False))
-
-            @pl.when(jnp.logical_not(seen))
-            def _():
-                mrow_ref[nd] = row
-                mcnt_ref[0] = nd + 1
+        if has_weights:
+            # scale in place, one position per step: the product is
+            # rounded to the buffer dtype before the bag reduce reads it,
+            # as in the reference's separate multiply (a multiply fused
+            # into the reduce would contract to an FMA and drift 1 ULP)
+            buf_ref[pl.ds(t, 1), :] = (buf_ref[pl.ds(t, 1), :]
+                                       * w_ref[s, i].astype(buf_ref.dtype))
 
         # Keep the pipeline full.
         @pl.when(t + distance < total)
@@ -201,15 +187,9 @@ def _fused_kernel(slot_ref, row_ref, w_ref, cache_ref, hot_ref,
         # is the wrapper's eager epilogue (see module docstring).
         @pl.when(i == pooling - 1)
         def _():
-            bag = pl.load(
-                buf_ref, (pl.ds(s * pooling, pooling), slice(None))
-            ).astype(f32)                                      # [L, D]
-            if has_weights:
-                wrow = pl.load(w_ref, (pl.ds(s, 1), slice(None)))
-                bag = bag * wrow.reshape(pooling, 1).astype(f32)
+            bag = buf_ref[pl.ds(s * pooling, pooling), :].astype(f32)  # [L, D]
             val = jnp.sum(bag, axis=0)
-            pl.store(out_ref, (pl.ds(s, 1), slice(None)),
-                     val[None, :].astype(out_ref.dtype))
+            out_ref[pl.ds(s, 1), :] = val[None, :].astype(out_ref.dtype)
 
         return 0
 
@@ -217,16 +197,13 @@ def _fused_kernel(slot_ref, row_ref, w_ref, cache_ref, hot_ref,
 
 
 def fused_warm_lookup_pallas(cache: jnp.ndarray, slots: jnp.ndarray,
-                             rows: jnp.ndarray,
                              weights: jnp.ndarray | None = None,
                              hot: jnp.ndarray | None = None, *,
                              opts: FusedLookupOpts = FusedLookupOpts()):
-    """Raw fixed-cap kernel launch. B % batch_block == 0 (wrapper pads).
+    """Raw kernel launch. B % batch_block == 0 (wrapper pads).
 
-    Always emits the raw (weighted) per-bag SUM — mean normalization is
-    the wrapper's eager epilogue. Returns (pooled [B, D], miss_rows [cap],
-    miss_pos [cap], counts [2]) where only the first counts[0] / counts[1]
-    miss entries are defined.
+    Always emits the raw (weighted) per-bag SUM [B, D] — mean
+    normalization is the wrapper's eager epilogue.
     """
     batch, pooling = slots.shape
     cache_rows, dim = cache.shape
@@ -236,7 +213,6 @@ def fused_warm_lookup_pallas(cache: jnp.ndarray, slots: jnp.ndarray,
     num_hot = int(hot.shape[0]) if hot is not None else 0
     has_weights = weights is not None
     distance = max(1, min(opts.prefetch_distance, bb * pooling))
-    cap = max(1, batch * pooling)
 
     kernel = functools.partial(
         _fused_kernel, pooling=pooling, distance=distance, num_hot=num_hot,
@@ -244,21 +220,19 @@ def fused_warm_lookup_pallas(cache: jnp.ndarray, slots: jnp.ndarray,
 
     in_specs = [
         pl.BlockSpec((bb, pooling), lambda b: (b, 0), memory_space=pltpu.SMEM),
-        pl.BlockSpec((bb, pooling), lambda b: (b, 0), memory_space=pltpu.SMEM),
-        (pl.BlockSpec((bb, pooling), lambda b: (b, 0))
-         if has_weights else None),
+        (pl.BlockSpec((bb, pooling), lambda b: (b, 0),
+                      memory_space=pltpu.SMEM) if has_weights else None),
         pl.BlockSpec(memory_space=pl.ANY),     # cache payload stays in HBM
         (pl.BlockSpec((num_hot, dim), lambda b: (0, 0)) if num_hot else None),
     ]
     inputs = [slots.astype(jnp.int32),
-              rows.astype(jnp.int32),
               weights.astype(jnp.float32) if has_weights else None,
               cache,
               hot if num_hot else None]
     live = [i for i, s in enumerate(in_specs) if s is not None]
 
     def kernel_wrapper(*refs):
-        args = [None] * 5
+        args = [None] * 4
         for j, i in enumerate(live):
             args[i] = refs[j]
         kernel(*args, *refs[len(live):])
@@ -267,28 +241,14 @@ def fused_warm_lookup_pallas(cache: jnp.ndarray, slots: jnp.ndarray,
         kernel_wrapper,
         grid=(batch // bb,),
         in_specs=[in_specs[i] for i in live],
-        out_specs=[
-            pl.BlockSpec((bb, dim), lambda b: (b, 0)),
-            # miss outputs: full-extent blocks with a constant index map, so
-            # the sequential grid accumulates into ONE persistent buffer
-            pl.BlockSpec((cap,), lambda b: (0,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((cap,), lambda b: (0,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((2,), lambda b: (0,), memory_space=pltpu.SMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((batch, dim), cache.dtype),
-            jax.ShapeDtypeStruct((cap,), jnp.int32),
-            jax.ShapeDtypeStruct((cap,), jnp.int32),
-            jax.ShapeDtypeStruct((2,), jnp.int32),
-        ],
+        out_specs=pl.BlockSpec((bb, dim), lambda b: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((batch, dim), cache.dtype),
         scratch_shapes=[
             pltpu.VMEM((bb * pooling, dim), cache.dtype),  # DMA dst dtype
             pltpu.SemaphoreType.DMA((distance,)),
         ],
-        # CompilerParams was TPUCompilerParams before jax 0.5; support both
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
-            dimension_semantics=("arbitrary",),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
         ),
         interpret=opts.interpret,
     )(*[inputs[i] for i in live])
@@ -305,8 +265,8 @@ def fused_warm_lookup_xla(cache: jnp.ndarray, slots: jnp.ndarray,
     multiply, `sum(axis=1)`, late divide — EAGERLY (a jitted wrapper would
     re-fuse mul+sum and drift 1 ULP), so the pooled output is bit-exact
     with `embedding_bag_ref` on the miss-zeroed table by construction.
-    Returns only the pooled block; the caller derives the miss-list from
-    the slot-map it built (`_miss_list_from_slots`).
+    Returns only the pooled block (`fused_warm_lookup` reads the miss-list
+    off the slot-map).
     """
     cache_rows = cache.shape[0]
     num_hot = int(hot.shape[0]) if hot is not None else 0
@@ -337,8 +297,8 @@ def fused_warm_lookup_xla(cache: jnp.ndarray, slots: jnp.ndarray,
 
 def _miss_list_from_slots(slots: np.ndarray,
                           rows: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    """Host-side miss-list oracle: (sorted distinct rows, ascending flat
-    occurrence positions) for slot==MISS entries. PAD entries are silent."""
+    """The miss-list: (sorted distinct rows, ascending flat occurrence
+    positions) of the slot==MISS entries. PAD entries are silent."""
     flat_slots = np.asarray(slots).ravel()
     flat_rows = np.asarray(rows).ravel()
     pos = np.flatnonzero(flat_slots == MISS).astype(np.int32)
@@ -383,12 +343,12 @@ def fused_warm_lookup(cache, slots, rows, weights=None, hot=None, *,
         return FusedLookupResult(pooled, np.empty(0, np.int32),
                                  np.empty(0, np.int32))
 
+    miss_rows, miss_pos = _miss_list_from_slots(slots_np, rows_np)
     if backend == "xla":
         pooled = fused_warm_lookup_xla(
             cache, slots_np, rows_np,
             None if weights is None else jnp.asarray(weights),
             None if hot is None else jnp.asarray(hot), mode=mode)
-        miss_rows, miss_pos = _miss_list_from_slots(slots_np, rows_np)
         return FusedLookupResult(pooled, miss_rows, miss_pos)
     if backend != "pallas":
         raise ValueError(f"unknown backend {backend!r}")
@@ -399,20 +359,18 @@ def fused_warm_lookup(cache, slots, rows, weights=None, hot=None, *,
     bb = opts.batch_block
     pad = (-batch) % bb
     if pad:
-        # dummy bags carry the PAD sentinel: zero contribution, no
-        # miss emission, sliced off below
+        # dummy bags carry the PAD sentinel: zero contribution, sliced
+        # off below
         slots_np = np.concatenate(
             [slots_np, np.full((pad, pooling), PAD, slots_np.dtype)])
-        rows_np = np.concatenate(
-            [rows_np, np.zeros((pad, pooling), rows_np.dtype)])
     w = None
     if weights is not None:
         w = jnp.asarray(weights)
         if pad:
             w = jnp.concatenate(
                 [w, jnp.zeros((pad, pooling), w.dtype)], axis=0)
-    pooled, mrow, mpos, mcnt = fused_warm_lookup_pallas(
-        cache, jnp.asarray(slots_np), jnp.asarray(rows_np), w,
+    pooled = fused_warm_lookup_pallas(
+        cache, jnp.asarray(slots_np), w,
         None if hot is None else jnp.asarray(hot), opts=opts)
     pooled = pooled[:batch]
     # mean epilogue: eager, op-for-op the reference's division (runtime
@@ -425,10 +383,6 @@ def fused_warm_lookup(cache, slots, rows, weights=None, hot=None, *,
             pooled = pooled / jnp.asarray(pooling, dtype=pooled.dtype)
     elif mode != "sum":
         raise ValueError(f"unknown mode {mode!r}")
-    mcnt = np.asarray(mcnt)
-    # trim to the live counts; sort distinct rows so both backends agree
-    miss_rows = np.sort(np.asarray(mrow[:mcnt[0]], np.int32))
-    miss_pos = np.asarray(mpos[:mcnt[1]], np.int32)
     return FusedLookupResult(pooled, miss_rows, miss_pos)
 
 

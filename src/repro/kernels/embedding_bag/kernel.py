@@ -15,13 +15,23 @@ TPU adaptation of the paper's three mechanisms (see DESIGN.md §2):
   VMEM footprint of (pinned rows + pipeline buffers + output block) is the
   analogue of the register budget.
 
+One launch serves a whole `[T, R, D]` table stack: the grid is
+(table, batch block), the stack stays in HBM (`memory_space=ANY`) and each
+row DMA addresses `table_ref.at[t, row]`. The indices, weights, hot block
+and output are blocked per table. (A `jax.vmap` over a single-table
+kernel cannot lower: Mosaic accepts an ANY-space operand only as one
+whole, unblocked array.)
+
 The pipeline is *flattened* over (sample, lookup) so row DMAs stream across
 bag boundaries with no per-sample drain bubble — a beyond-paper improvement
 (the paper's per-CUDA-thread pipeline restarts at each bag).
 
-Layout notes (TPU): rows are [D] f32/bf16 with D a multiple of 128 preferred
-(lane dimension). The reduce is a VPU add over [1, D] tiles; `group_size`
-(perf knob) batches `g` pending rows into one [g, D] VPU reduction.
+Layout notes (TPU): rows are [D] f32 with D a multiple of 128 preferred
+(lane dimension). Tables must be float32: a packed dtype (bf16) stores
+row pairs in one 32-bit word of an (8, 128) tile, and Mosaic refuses the
+kernel's single-row slices of such a layout ("cannot statically prove
+that index ... is a multiple of 8"). `embedding_bag_pallas` rejects such
+tables up front.
 """
 from __future__ import annotations
 
@@ -54,34 +64,35 @@ class EmbeddingBagOpts:
 def _bag_kernel(idx_ref, w_ref, table_ref, hot_ref, out_ref, buf_ref, sem_ref,
                 *, pooling: int, distance: int, num_hot: int, mode: str,
                 has_weights: bool):
-    """One grid step: `batch_block` bags, flattened software pipeline.
+    """One grid step (t, b): `batch_block` bags of table t, flattened
+    software pipeline.
 
     idx_ref: SMEM [batch_block, pooling] int32 (hot-first remapped)
     w_ref:   SMEM [batch_block, pooling] f32 or None
-    table_ref: HBM [R, D] (memory_space=ANY; manual DMA only)
+    table_ref: HBM [T, R, D] (memory_space=ANY; manual DMA only)
     hot_ref: VMEM [num_hot, D] or None
     out_ref: VMEM [batch_block, D]
     buf_ref: VMEM scratch [distance, D]
     sem_ref: DMA semaphores [distance]
     """
+    tbl = pl.program_id(0)
     bb = out_ref.shape[0]
     dim = out_ref.shape[1]
     total = bb * pooling
     f32 = jnp.float32
 
-    def row_of(t):
-        return idx_ref[t // pooling, t % pooling]
+    def row_dma(row, slot):
+        return pltpu.make_async_copy(
+            table_ref.at[tbl, row], buf_ref.at[slot],
+            sem_ref.at[slot])
 
     def start_fetch(t):
         """Begin the HBM->VMEM row DMA for flat step t (cold rows only)."""
-        row = row_of(t)
-        slot = jax.lax.rem(t, distance)
+        row = idx_ref[t // pooling, t % pooling]
 
         @pl.when(row >= num_hot)
         def _():
-            pltpu.make_async_copy(
-                table_ref.at[row], buf_ref.at[slot], sem_ref.at[slot]
-            ).start()
+            row_dma(row, jax.lax.rem(t, distance)).start()
 
     # Prologue: fill the pipeline `distance` deep (paper: prefetch distance).
     for j in range(min(distance, total)):
@@ -102,17 +113,12 @@ def _bag_kernel(idx_ref, w_ref, table_ref, hot_ref, out_ref, buf_ref, sem_ref,
         # Consume: wait on the DMA for cold rows; hot rows read VMEM directly.
         @pl.when(jnp.logical_not(is_hot))
         def _():
-            pltpu.make_async_copy(
-                table_ref.at[row], buf_ref.at[slot], sem_ref.at[slot]
-            ).wait()
+            row_dma(row, slot).wait()
 
-        cold_row = pl.load(buf_ref, (pl.ds(slot, 1), slice(None)))   # [1, D]
+        row_vec = buf_ref[pl.ds(slot, 1), :]                   # [1, D]
         if num_hot > 0:
             safe = jnp.minimum(row, num_hot - 1)
-            hot_row = pl.load(hot_ref, (pl.ds(safe, 1), slice(None)))
-            row_vec = jnp.where(is_hot, hot_row, cold_row)
-        else:
-            row_vec = cold_row
+            row_vec = jnp.where(is_hot, hot_ref[pl.ds(safe, 1), :], row_vec)
         row_vec = row_vec.astype(f32)
 
         if has_weights:
@@ -136,8 +142,7 @@ def _bag_kernel(idx_ref, w_ref, table_ref, hot_ref, out_ref, buf_ref, sem_ref,
                 val = acc / denom
             else:
                 val = acc
-            pl.store(out_ref, (pl.ds(s, 1), slice(None)),
-                     val[None, :].astype(out_ref.dtype))
+            out_ref[pl.ds(s, 1), :] = val[None, :].astype(out_ref.dtype)
 
         return acc, wsum
 
@@ -145,41 +150,50 @@ def _bag_kernel(idx_ref, w_ref, table_ref, hot_ref, out_ref, buf_ref, sem_ref,
     jax.lax.fori_loop(0, total, body, init)
 
 
-def embedding_bag_pallas(table: jnp.ndarray, indices: jnp.ndarray,
+def embedding_bag_pallas(tables: jnp.ndarray, indices: jnp.ndarray,
                          weights: jnp.ndarray | None = None,
                          opts: EmbeddingBagOpts = EmbeddingBagOpts()) -> jnp.ndarray:
-    """Fixed-pooling embedding bag via the Pallas pipeline kernel.
+    """Fixed-pooling embedding bag over a table stack, one Pallas launch.
 
-    table:   [R, D] (if opts.num_hot > 0, must already be hot-first ordered and
-             `indices` remapped — see core/hot_cache.HotPlan)
-    indices: [B, L] int32, B % opts.batch_block == 0 (ops.py pads)
-    returns: [B, D] in table.dtype
+    tables:  [T, R, D] (if opts.num_hot > 0, each table must already be
+             hot-first ordered and `indices` remapped — see
+             core/hot_cache.HotPlan)
+    indices: [T, B, L] int32, B % opts.batch_block == 0 (ops.py pads)
+    weights: [T, B, L] or None
+    returns: [T, B, D] float32
     """
-    batch, pooling = indices.shape
-    _, dim = table.shape
+    if tables.dtype != jnp.float32:
+        raise ValueError(
+            f"the Pallas embedding-bag kernel takes float32 tables, got "
+            f"{tables.dtype}: Mosaic refuses the single-row slices of a "
+            f"packed dtype (use backend='xla' for {tables.dtype} tables)")
+    num_tables, batch, pooling = indices.shape
+    dim = tables.shape[2]
     bb = opts.batch_block
     if batch % bb:
         raise ValueError(f"batch {batch} not divisible by batch_block {bb}")
     distance = max(1, min(opts.prefetch_distance, bb * pooling))
-    num_hot = int(min(opts.num_hot, table.shape[0]))
+    num_hot = int(min(opts.num_hot, tables.shape[1]))
     has_weights = weights is not None
 
     kernel = functools.partial(
         _bag_kernel, pooling=pooling, distance=distance, num_hot=num_hot,
         mode=opts.mode, has_weights=has_weights)
 
-    grid = (batch // bb,)
+    # per-table blocks; `None` squeezes the table axis out of the kernel view
+    bag_spec = pl.BlockSpec((None, bb, pooling), lambda t, b: (t, b, 0),
+                            memory_space=pltpu.SMEM)
     in_specs = [
-        pl.BlockSpec((bb, pooling), lambda b: (b, 0), memory_space=pltpu.SMEM),
-        (pl.BlockSpec((bb, pooling), lambda b: (b, 0), memory_space=pltpu.SMEM)
-         if has_weights else None),
-        pl.BlockSpec(memory_space=pl.ANY),  # table stays in HBM
-        (pl.BlockSpec((num_hot, dim), lambda b: (0, 0)) if num_hot else None),
+        bag_spec,
+        bag_spec if has_weights else None,
+        pl.BlockSpec(memory_space=pl.ANY),  # table stack stays in HBM
+        (pl.BlockSpec((None, num_hot, dim), lambda t, b: (t, 0, 0))
+         if num_hot else None),
     ]
     inputs = [indices.astype(jnp.int32),
               weights.astype(jnp.float32) if has_weights else None,
-              table,
-              table[:num_hot] if num_hot else None]
+              tables,
+              tables[:, :num_hot] if num_hot else None]
 
     # Drop the unused operand slots (w/ matching kernel signature via wrapper).
     live = [i for i, s in enumerate(in_specs) if s is not None]
@@ -193,18 +207,16 @@ def embedding_bag_pallas(table: jnp.ndarray, indices: jnp.ndarray,
 
     return pl.pallas_call(
         kernel_wrapper,
-        grid=grid,
+        grid=(num_tables, batch // bb),
         in_specs=[in_specs[i] for i in live],
-        out_specs=pl.BlockSpec((bb, dim), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((batch, dim), table.dtype),
+        out_specs=pl.BlockSpec((None, bb, dim), lambda t, b: (t, b, 0)),
+        out_shape=jax.ShapeDtypeStruct((num_tables, batch, dim), tables.dtype),
         scratch_shapes=[
-            pltpu.VMEM((distance, dim), table.dtype),  # DMA dst dtype == src
+            pltpu.VMEM((distance, dim), tables.dtype),  # DMA dst == src
             pltpu.SemaphoreType.DMA((distance,)),
         ],
-        # CompilerParams was TPUCompilerParams before jax 0.5; support both
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
-            dimension_semantics=("arbitrary",),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
         ),
         interpret=opts.interpret,
     )(*[inputs[i] for i in live])

@@ -9,6 +9,7 @@ Backend selection:
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -23,17 +24,36 @@ def _on_tpu() -> bool:
 
 
 def _pad_batch(indices: jnp.ndarray, weights: jnp.ndarray | None, bb: int):
-    """Pad batch up to a multiple of batch_block with zero-weight dummy bags."""
-    batch = indices.shape[0]
+    """Pad the batch axis (-2 of [T, B, L]) up to a multiple of batch_block
+    with zero-weight dummy bags."""
+    batch = indices.shape[-2]
     pad = (-batch) % bb
     if pad == 0:
         return indices, weights, batch
-    idx_pad = jnp.zeros((pad, indices.shape[1]), indices.dtype)
-    indices = jnp.concatenate([indices, idx_pad], axis=0)
+    widths = [(0, 0)] * (indices.ndim - 2) + [(0, pad), (0, 0)]
+    indices = jnp.pad(indices, widths)
     if weights is not None:
-        w_pad = jnp.zeros((pad, weights.shape[1]), weights.dtype)
-        weights = jnp.concatenate([weights, w_pad], axis=0)
+        weights = jnp.pad(weights, widths)
     return indices, weights, batch
+
+
+@functools.partial(jax.jit, static_argnames=("mode", "opts"))
+def embedding_bag_stacked(tables: jnp.ndarray, indices: jnp.ndarray,
+                          weights: jnp.ndarray | None = None, *,
+                          mode: str = "sum",
+                          opts: EmbeddingBagOpts | None = None) -> jnp.ndarray:
+    """The Pallas kernel over a table stack, one launch:
+    [T,R,D] x [T,B,L] -> [T,B,D].
+
+    When `opts.num_hot > 0` the caller is responsible for hot-first table
+    order + remapped indices (core.embedding.EmbeddingBagCollection does this).
+    """
+    opts = opts or EmbeddingBagOpts()
+    opts = dataclasses.replace(opts, mode=mode,
+                               interpret=opts.interpret or not _on_tpu())
+    indices, weights, batch = _pad_batch(indices, weights, opts.batch_block)
+    out = embedding_bag_pallas(tables, indices, weights, opts)
+    return out[:, :batch]
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "backend", "opts"))
@@ -41,25 +61,17 @@ def embedding_bag(table: jnp.ndarray, indices: jnp.ndarray,
                   weights: jnp.ndarray | None = None, *, mode: str = "sum",
                   backend: str = "auto",
                   opts: EmbeddingBagOpts | None = None) -> jnp.ndarray:
-    """Fixed-pooling embedding bag: [R,D] x [B,L] -> [B,D].
-
-    When `opts.num_hot > 0` the caller is responsible for hot-first table
-    order + remapped indices (core.embedding.EmbeddingBagCollection does this).
-    """
+    """Fixed-pooling embedding bag: [R,D] x [B,L] -> [B,D]."""
     if backend == "auto":
         backend = "pallas" if _on_tpu() else "xla"
     if backend == "xla":
         return ref.embedding_bag_ref(table, indices, weights, mode=mode)
     if backend != "pallas":
         raise ValueError(f"unknown backend {backend!r}")
-    opts = opts or EmbeddingBagOpts()
-    if opts.mode != mode:
-        opts = EmbeddingBagOpts(**{**opts.__dict__, "mode": mode})
-    if not _on_tpu() and not opts.interpret:
-        opts = EmbeddingBagOpts(**{**opts.__dict__, "interpret": True})
-    indices, weights, batch = _pad_batch(indices, weights, opts.batch_block)
-    out = embedding_bag_pallas(table, indices, weights, opts)
-    return out[:batch]
+    out = embedding_bag_stacked(
+        table[None], indices[None],
+        None if weights is None else weights[None], mode=mode, opts=opts)
+    return out[0]
 
 
 def embedding_lookup(table: jnp.ndarray, token_ids: jnp.ndarray, *,

@@ -277,8 +277,8 @@ class ParameterServer:
         [B, T, D] as a device-resident jax array.
 
         One fused launch per table over the device warm payload does
-        hit-gather + pooled reduction + miss-list emission; only the
-        emitted misses then touch the host cold path (gather + admit +
+        hit-gather + pooled reduction; only the slot-map's misses then
+        touch the host cold path (gather + admit +
         whole-bag recompute via `complete_miss_bags`), replacing the
         per-index Python round trip of `lookup()` + host pooling. Output
         is bit-identical to pooling `lookup()`'s rows with

@@ -187,8 +187,8 @@ class DeviceWarmCache(WarmCache):
     The device payload additionally powers the FUSED lookup path
     (`kernels.embedding_bag.fused`): `build_slot_map()` turns raw row ids
     into the kernel's slot-map and `lookup_fused()` runs hit-gather +
-    pooled reduce + miss-list emission in one launch over `data`, without
-    ever reading hit payloads back to the host.
+    pooled reduce in one launch over `data` (the miss-list comes off the
+    slot-map), without ever reading hit payloads back to the host.
     """
 
     supports_fused = True

@@ -151,7 +151,11 @@ class ServingSession:
     # -- engine -------------------------------------------------------------
     def _build_engine(self, caps):
         """Pick the forward shape from the capability descriptor — the only
-        place residency is ever consulted."""
+        place residency is ever consulted. `self.engine_jit` keeps the
+        jitted program each batch runs (the whole forward taking
+        `(params, dense, indices)` when device-resident, else the
+        post-lookup remainder taking `(dense, pooled)`), so a caller can
+        lower it and inspect what was compiled."""
         model, params = self.model, self.params
         if caps.device_resident:
             # params ride as a per-call ARGUMENT, not a closure capture: a
@@ -159,8 +163,10 @@ class ServingSession:
             # an online update (which swaps params["tables"] inside this
             # dict) would be invisible to the compiled engine forever
             jitted = jax.jit(lambda p, d, i: model.forward(p, d, i))
+            self.engine_jit = jitted
             return lambda d, i: jitted(self.params, d, i)
         rest = jax.jit(lambda d, p: model.forward_from_pooled(params, d, p))
+        self.engine_jit = rest
 
         def forward(dense, idx):
             pooled = model.ebc.apply(params, idx)   # host lookup

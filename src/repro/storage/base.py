@@ -75,8 +75,8 @@ class StorageCapabilities:
     # overload. False (the default) means set_degraded is an inert no-op.
     degradable: bool = False
     # lookup() serves warm/hot hits through the fused kernel path: slot-map
-    # build -> one fused launch (hit-gather + pooled reduce + miss-list) ->
-    # host cold path only for the emitted misses. Requires
+    # build -> one fused launch (hit-gather + pooled reduce) -> host cold
+    # path only for the slot-map's misses. Requires
     # PSConfig.fused_lookup=True and a device-resident warm payload; the
     # per-row Python path serves otherwise (same bits either way).
     fused_lookup: bool = False

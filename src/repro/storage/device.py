@@ -16,7 +16,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.embedding_bag import embedding_bag
+from repro.kernels.embedding_bag import embedding_bag_stacked
 from repro.storage.base import EmbeddingStorage, StorageCapabilities
 from repro.storage.registry import register
 
@@ -139,15 +139,10 @@ class DeviceStorage(EmbeddingStorage):
                 lambda t, i: jnp.take(t, i, axis=0))(tables, idx_t)  # [T,B,L,D]
             pooled = _pool_rows_core(rows, w_t, cfg.combine, cfg.pooling)
         else:
-            opts = cfg.kernel_opts(interpret=jax.default_backend() != "tpu")
-
-            def one(table, idx, w):
-                return embedding_bag(table, idx, w, mode=cfg.combine,
-                                     backend="pallas", opts=opts)
-            if w_t is None:
-                pooled = jax.vmap(lambda t, i: one(t, i, None))(tables, idx_t)
-            else:
-                pooled = jax.vmap(one)(tables, idx_t, w_t)
+            # one launch over the whole stack (off the TPU: interpret mode)
+            pooled = embedding_bag_stacked(tables, idx_t, w_t,
+                                           mode=cfg.combine,
+                                           opts=cfg.kernel_opts())
         pooled = pspec.constrain_tablewise(pooled)     # [T(+pad), B, D]
         pooled = jnp.swapaxes(pooled, 0, 1)            # [B, T(+pad), D]
         if cfg.shard_pad_tables:

@@ -43,6 +43,7 @@ import multiprocessing
 from collections import deque
 from typing import Any, Optional, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -59,6 +60,27 @@ from repro.storage.sharded import (_chunk_bounds, merge_shard_stats,
 from repro.storage.tenancy import TenantNamespace, resolve_tenants
 from repro.storage.tiered import (_extract_tables, _reject_double_remap,
                                   build_ps_config)
+
+
+def _parent_platform() -> str:
+    """The platform this (parent) process serves on."""
+    return jax.default_backend()
+
+
+def _check_worker_backing(ps_cfg) -> None:
+    """Workers run JAX on the host CPU (`spawn_worker`), so a worker's
+    "device" warm cache is a CPU buffer. Where the parent serves on the
+    CPU too that is the device; where it serves on an accelerator, a
+    device-backed or fused pool would quietly serve from worker CPUs."""
+    platform = _parent_platform()
+    if platform != "cpu" and (ps_cfg.warm_backing == "device"
+                              or ps_cfg.fused_lookup):
+        raise ValueError(
+            f"the pool backend's workers run on the host CPU, but this "
+            f"process serves on {platform!r}: warm_backing='device' and "
+            f"fused_lookup=True would put the device cache on worker CPUs. "
+            f"Use warm_backing='host' with the pool, or the 'tiered'/"
+            f"'sharded' backend for a device-resident warm cache")
 
 
 @dataclasses.dataclass
@@ -296,6 +318,7 @@ class PoolStorage(EmbeddingStorage):
         ps_cfg = build_ps_config(trace, cfg.rows, cfg.dim,
                                  cfg.jnp_dtype.itemsize, ps_cfg,
                                  device_budget_bytes, **ps_cfg_overrides)
+        _check_worker_backing(ps_cfg)
         tables = np.ascontiguousarray(
             _extract_tables(params, cfg.num_tables))
         spaces = (resolve_tenants(tenants, cfg.num_tables)

@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import dataclasses
 import multiprocessing
+import os
 import threading
 import time
 from multiprocessing import shared_memory
@@ -54,6 +55,10 @@ SHM_INLINE_MAX = 16 * 1024
 #: default per-call timeout (seconds) — generous because a worker's first
 #: verb pays the spawn-side jax import
 DEFAULT_TIMEOUT = 120.0
+
+#: serializes the environment swap around a worker's start (see
+#: `spawn_worker`)
+_SPAWN_ENV_LOCK = threading.Lock()
 
 
 class WorkerDeadError(RuntimeError):
@@ -294,13 +299,28 @@ class WorkerTransport:
 
 def spawn_worker(worker: int, ctx=None) -> WorkerTransport:
     """Start one pool worker process (spawn context: the parent holds JAX
-    worker threads, which fork() cannot safely cross)."""
+    worker threads, which fork() cannot safely cross).
+
+    The worker runs JAX on the host CPU: the parent may hold the
+    accelerator, and a chip belongs to one process — a worker that opened
+    it would fail or hang. A spawned interpreter inherits the environment
+    at `start()`, so `JAX_PLATFORMS=cpu` is in place before anything in
+    it (the re-imported main module included) imports JAX."""
     from repro.storage.pool.worker import worker_main
     if ctx is None:
         ctx = multiprocessing.get_context("spawn")
     parent_conn, child_conn = ctx.Pipe(duplex=True)
     proc = ctx.Process(target=worker_main, args=(worker, child_conn),
                        name=f"pool-worker-{worker}", daemon=True)
-    proc.start()
+    with _SPAWN_ENV_LOCK:
+        saved = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            proc.start()
+        finally:
+            if saved is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = saved
     child_conn.close()
     return WorkerTransport(proc, parent_conn, worker)

@@ -15,7 +15,8 @@ verb set over the pipe, plus lifecycle verbs:
                        are fully constructed on every worker FIRST
                        (serving untouched), then committed everywhere —
                        or aborted everywhere, leaving the old units live.
-  ping / shutdown    — heartbeat and clean exit.
+  ping / shutdown    — heartbeat (pid, hosted units, the worker's
+                       JAX_PLATFORMS — always "cpu") and clean exit.
 
 Shared host cold tier: a unit whose table ids form one ascending
 contiguous run is served a zero-copy VIEW into the shared segment
@@ -80,6 +81,7 @@ class _WorkerState:
     # -- lifecycle ----------------------------------------------------------
     def do_ping(self):
         return {"worker": self.worker, "pid": os.getpid(),
+                "jax_platforms": os.environ.get("JAX_PLATFORMS"),
                 "units": sorted(self.units),
                 "shards": sorted({u.shard for u in self.units.values()}),
                 "degraded": self.degraded}

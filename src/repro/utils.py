@@ -7,6 +7,7 @@ import json
 import logging
 import os
 import time
+from pathlib import Path
 from typing import Any, Callable, Iterator
 
 import jax
@@ -21,26 +22,36 @@ if not logger.handlers:  # pragma: no cover - import-time wiring
     logger.setLevel(os.environ.get("REPRO_LOGLEVEL", "INFO"))
 
 
+#: where the persistent compile cache lives when JAX_COMPILATION_CACHE_DIR
+#: is unset: a fixed, git-ignored directory in the checkout (the path is
+#: part of the cache key, so it must never move between runs)
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; entry points call this
+    once, before their first compile (tests never do).
+
+    `JAX_COMPILATION_CACHE_DIR`, when set, places the cache and nothing
+    here overrides it; otherwise it goes to `COMPILE_CACHE_DIR`. Every
+    program is cached, the Pallas kernels too — they compile in far less
+    than JAX's default one-second threshold. Returns the directory.
+    """
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return jax.config.jax_compilation_cache_dir
+
+
 def asdict_shallow(cfg: Any) -> dict:
     """dataclasses.asdict without deep-copying jnp arrays."""
     return {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
 
 
 def shard_map_compat(*, mesh, in_specs, out_specs, check_vma=True):
-    """Decorator form of shard_map across JAX versions.
-
-    Newer JAX exposes `jax.shard_map(..., check_vma=)`; older releases have
-    `jax.experimental.shard_map.shard_map(..., check_rep=)`. Same semantics.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-
-    def deco(fn):
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=check_vma)
-    return deco
+    """Decorator form of `jax.shard_map`."""
+    return jax.shard_map(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma)
 
 
 @contextlib.contextmanager

@@ -1,16 +1,8 @@
-import importlib.util
 import os
 import subprocess
 import sys
 
 import pytest
-
-# Property tests import `hypothesis`; fall back to the deterministic in-repo
-# stub (tests/_stubs/) when the real library is not installed. conftest runs
-# before test-module collection, so the path is ready in time.
-if importlib.util.find_spec("hypothesis") is None:
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                    "_stubs"))
 
 # NOTE (per instructions): XLA_FLAGS / host-device-count is deliberately NOT
 # set here — unit tests see the real single CPU device. Multi-device tests run

@@ -42,15 +42,21 @@ def test_kernel_matches_ref_shapes(rows, dim, batch, pooling, distance):
                                atol=1e-5)
 
 
-@pytest.mark.parametrize("dtype,tol", [(np.float32, 1e-5), (jnp.bfloat16, 2e-2)])
-def test_kernel_dtypes(dtype, tol):
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_kernel_dtypes(dtype):
+    """float32 tables match the reference; packed bf16 tables are refused
+    up front (Mosaic cannot address their single rows on the TPU)."""
     table, idx = _mk(128, 128, 8, 6, dtype=np.float32)
     table = table.astype(dtype)
     opts = EmbeddingBagOpts(prefetch_distance=4, batch_block=4, interpret=True)
+    if dtype == jnp.bfloat16:
+        with pytest.raises(ValueError, match="float32 tables, got bfloat16"):
+            embedding_bag(table, idx, backend="pallas", opts=opts)
+        return
     out = embedding_bag(table, idx, backend="pallas", opts=opts)
     ref = embedding_bag_ref(table, idx)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5,
+                               atol=1e-5)
 
 
 @pytest.mark.parametrize("num_hot", [0, 1, 16, 128])

@@ -26,6 +26,8 @@ Pins the acceptance contract of the `"pool"` backend:
     pool tenancy is STATIC (attach/detach raise — rebuild instead), and
     per-tenant depth/degraded knobs survive a worker respawn.
 """
+import os
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -206,6 +208,36 @@ def test_pool_fused_bit_exact(dense_ref):
         assert ebc.storage.capabilities().fused_lookup
         for seed in range(3):
             _check(ebc, ebc0, params, pats, seed)
+
+
+def test_pool_refuses_device_cache_off_cpu(dense_ref, monkeypatch):
+    """Workers run JAX on the host CPU, so when the parent serves on the
+    chip a device-backed (or fused) pool is refused before any worker
+    spawns — never quietly built on worker CPUs."""
+    import importlib
+    pool_mod = importlib.import_module("repro.storage.pool.pool")
+    _, params = dense_ref
+    monkeypatch.setattr(pool_mod, "_parent_platform", lambda: "tpu")
+    monkeypatch.setattr(pool_mod, "spawn_worker", lambda *a, **k:
+                        pytest.fail("a refused build spawned a worker"))
+    for kw in (dict(warm_backing="device"),
+               dict(warm_backing="device", fused_lookup=True)):
+        ebc = EmbeddingBagCollection(_stage_cfg("pool"))
+        with pytest.raises(ValueError, match="worker CPUs"):
+            ebc.storage.build(params, PSConfig(hot_rows=16, warm_slots=16,
+                                               **kw), num_workers=2)
+
+
+def test_pool_worker_runs_jax_on_cpu(monkeypatch):
+    """A spawned worker sees JAX_PLATFORMS=cpu whatever the parent's
+    environment says, and the parent's environment is left as it was."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    t = spawn_worker(0)
+    try:
+        assert t.ping()["jax_platforms"] == "cpu"
+    finally:
+        t.shutdown()
+    assert os.environ["JAX_PLATFORMS"] == "tpu"
 
 
 def test_pool_weighted_mean_bit_exact(dense_ref):
