@@ -56,7 +56,6 @@ import tempfile
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro import storage as storage_registry
@@ -192,7 +191,7 @@ def print_worker_status(storage) -> None:
     print(f"pool workers {alive}/{len(status)} alive  {cells}", flush=True)
 
 
-def run_session(args, hotness) -> tuple[dict, int, float]:
+def run_session(args, hotness) -> tuple[dict, int]:
     """The current API: ServingSession owns engine + loop + storage."""
     cfg = DLRMConfig(embedding=EmbeddingStageConfig(
         num_tables=args.tables, rows=args.rows, dim=128,
@@ -260,22 +259,9 @@ def run_session(args, hotness) -> tuple[dict, int, float]:
         print_worker_status(model.ebc.storage)   # before close() joins them
         sess.close()    # install any in-flight async refresh before reading
         pct, viol = sess.percentiles(), sess.sla_violations()
-        emb_share = 0.0
-        if device_resident:
-            # embedding-stage share (paper Fig. 1)
-            # params ride as an argument: a closed-over table stack would
-            # be baked into the program as a constant
-            emb = jax.jit(model.embedding_only)
-            idx = jnp.asarray(stream.next_batch().indices)
-            jax.block_until_ready(emb(params, idx))  # compile outside timing
-            t0 = time.perf_counter()
-            jax.block_until_ready(emb(params, idx))
-            t_emb = time.perf_counter() - t0
-            emb_share = t_emb / max(np.mean(sess.stats.batch_latencies_s),
-                                    1e-9)
     if upd_dir is not None:
         upd_dir.cleanup()
-    return pct, viol, emb_share
+    return pct, viol
 
 
 def run_trace(args) -> None:
@@ -477,7 +463,7 @@ def main():
         return
     levels = HOTNESS if args.hotness == "all" else (args.hotness,)
     for hotness in levels:
-        pct, viol, emb_share = run_session(args, hotness)
+        pct, viol = run_session(args, hotness)
         line = (f"{hotness:9s} served={pct['served']:4d} "
                 f"p50={pct['p50_ms']:.1f}ms p99={pct['p99_ms']:.1f}ms "
                 f"batch={pct['mean_batch_ms']:.1f}ms "
@@ -496,8 +482,6 @@ def main():
                 line += f" migrations={pct['migrations']}"
             if "routing_updates" in pct:
                 line += f" reroutes={pct['routing_updates']}"
-        else:
-            line += f" emb_share~{min(emb_share, 1.0):.0%}"
         if "model_version" in pct:
             line += (f" v={pct['model_version']} "
                      f"updates={pct['updates_applied']}"

@@ -251,6 +251,7 @@ def fused_warm_lookup_pallas(cache: jnp.ndarray, slots: jnp.ndarray,
             dimension_semantics=("parallel",),
         ),
         interpret=opts.interpret,
+        name="fused_embedding_bag",
     )(*[inputs[i] for i in live])
 
 

@@ -219,4 +219,5 @@ def embedding_bag_pallas(tables: jnp.ndarray, indices: jnp.ndarray,
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=opts.interpret,
+        name="embedding_bag",
     )(*[inputs[i] for i in live])

@@ -35,6 +35,7 @@ import time
 from typing import Callable, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -288,9 +289,9 @@ class InferenceServer:
             idx[i] = q.indices
         return idx
 
-    def _assemble(self, batch: list[Query]):
-        cfg = self.batcher.cfg
-        b = cfg.max_batch if cfg.pad_to_max else len(batch)
+    def _assemble(self, batch: list[Query], b: int):
+        """[b, F] dense and [b, T, L] indices; rows past len(batch) stay
+        zero."""
         dense = np.zeros((b,) + batch[0].dense.shape, np.float32)
         for i, q in enumerate(batch):
             dense[i] = q.dense
@@ -345,53 +346,77 @@ class InferenceServer:
         self.stats.ps_stats = self.storage.stats()
 
     def poll(self, force: bool = False) -> int:
-        """Execute at most one batch; returns #queries served."""
+        """Execute at most one batch; returns #queries served.
+
+        A poll that serves a batch runs under the profiler span
+        `serve.batch`, whose children split it: `serve.assemble`,
+        `serve.stage`, `serve.forward` (exactly the batch service time)
+        and `serve.record`. Their statistics are per-batch sums, so no
+        span is opened per query; with no profiler running a span costs
+        about a microsecond (docs/serving.md, "Tracing a served batch")."""
         batch = self.batcher.next_batch(force=force)
         if not batch:
             return 0
+        popped = self.clock()
         n = len(batch)
-        dense, idx = self._assemble(batch)
+        cfg = self.batcher.cfg
+        b = cfg.max_batch if cfg.pad_to_max else n
+        # queue waits as sums: the batch is FIFO, so its head waited most
+        with TraceAnnotation(
+                "serve.batch", queries=n, padded=b,
+                wait_s_sum=n * popped - sum(q.arrival_s for q in batch),
+                wait_s_max=popped - batch[0].arrival_s):
+            return self._serve(batch, b)
+
+    def _serve(self, batch: list[Query], b: int) -> int:
+        n = len(batch)
+        with TraceAnnotation("serve.assemble"):
+            dense, idx = self._assemble(batch, b)
         if self.storage is not None:
             # both run outside the timed region. Install a finished
             # refresh FIRST so staging probes the post-refresh tier state
             # (staging against the old plan would prefetch rows about to
             # become hot and skip warm rows about to be invalidated).
-            self._install_refresh_if_ready()
-            # staging models work that overlaps the PREVIOUS batch's
-            # compute, so it must not bill this batch
-            self._stage_next()
-            # batcher padding is not traffic — keep it out of cache stats
-            # and the refresh window
-            self.storage.hint_valid(n)
-        t0 = time.perf_counter()
-        scores = self.forward(dense, idx)
-        np.asarray(scores)  # block
-        t1 = time.perf_counter()
-        if self.on_batch is not None:
-            self.on_batch(batch, np.asarray(scores)[:n])
-        # batch service time is always REAL seconds (it feeds the deadline
-        # admission's EWMA); a virtual clock advances by exactly that
-        # duration, so query latencies = virtual queueing delay + real
-        # service — deterministic offered load, honest service cost
-        service = t1 - t0
-        self.batcher.observe_service(service)
-        if self._clock_advance is not None:
-            self._clock_advance(service)
-            done = self.clock()
-        else:
-            done = t1
-        self.stats.batch_latencies_s.append(service)
-        for q in batch:
-            self.stats.query_latencies_s.append(done - q.arrival_s)
-        self.stats.served += n
-        self.stats.request_queue_len = len(self.batcher.queue)
-        if self.storage is not None:
-            self._executed_batches += 1
-            if (self.refresh_every_batches
-                    and self._executed_batches
-                    % self.refresh_every_batches == 0):
-                self._start_refresh()
-            self.stats.ps_stats = self.storage.stats()
+            with TraceAnnotation("serve.stage"):
+                self._install_refresh_if_ready()
+                # staging models work that overlaps the PREVIOUS batch's
+                # compute, so it must not bill this batch
+                self._stage_next()
+                # batcher padding is not traffic — keep it out of cache
+                # stats and the refresh window
+                self.storage.hint_valid(n)
+        with TraceAnnotation("serve.forward"):
+            t0 = time.perf_counter()
+            scores = self.forward(dense, idx)
+            np.asarray(scores)  # block
+            t1 = time.perf_counter()
+        with TraceAnnotation("serve.record"):
+            if self.on_batch is not None:
+                self.on_batch(batch, np.asarray(scores)[:n])
+            # batch service time is always REAL seconds (it feeds the
+            # deadline admission's EWMA); a virtual clock advances by
+            # exactly that duration, so query latencies = virtual queueing
+            # delay + real service — deterministic offered load, honest
+            # service cost
+            service = t1 - t0
+            self.batcher.observe_service(service)
+            if self._clock_advance is not None:
+                self._clock_advance(service)
+                done = self.clock()
+            else:
+                done = t1
+            self.stats.batch_latencies_s.append(service)
+            for q in batch:
+                self.stats.query_latencies_s.append(done - q.arrival_s)
+            self.stats.served += n
+            self.stats.request_queue_len = len(self.batcher.queue)
+            if self.storage is not None:
+                self._executed_batches += 1
+                if (self.refresh_every_batches
+                        and self._executed_batches
+                        % self.refresh_every_batches == 0):
+                    self._start_refresh()
+                self.stats.ps_stats = self.storage.stats()
         return n
 
     def drain(self, timeout_s: float = 10.0, poll=None) -> None:

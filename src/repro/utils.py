@@ -1,14 +1,13 @@
 """Shared small utilities: typed dataclass configs, timing, logging, tree math."""
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import json
 import logging
 import os
 import time
 from pathlib import Path
-from typing import Any, Callable, Iterator
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -52,16 +51,6 @@ def shard_map_compat(*, mesh, in_specs, out_specs, check_vma=True):
     """Decorator form of `jax.shard_map`."""
     return jax.shard_map(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                          check_vma=check_vma)
-
-
-@contextlib.contextmanager
-def timed(label: str, sink: dict | None = None) -> Iterator[None]:
-    t0 = time.perf_counter()
-    yield
-    dt = time.perf_counter() - t0
-    if sink is not None:
-        sink[label] = dt
-    logger.debug("%s took %.3fs", label, dt)
 
 
 def timeit_median(fn: Callable[[], Any], iters: int = 5, warmup: int = 2) -> float:

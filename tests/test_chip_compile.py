@@ -73,8 +73,9 @@ def test_device_backend_lookup_compiles(one_chip, monkeypatch, pinned,
     params = {"tables": _spec((T, R, D), jnp.float32, one_chip)}
     idx = _spec((B, T, L), jnp.int32, one_chip)
     w = _spec((B, T, L), jnp.float32, one_chip) if weighted else None
-    compiled = jax.jit(ebc.apply).lower(params, idx, w).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(ebc.apply).lower(params, idx, w).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "%embedding_bag." in text      # the name the device trace shows
 
 
 @pytest.mark.parametrize("weighted", [False, True])
@@ -86,11 +87,12 @@ def test_fused_kernel_compiles_at_serving_batch(one_chip, num_hot, weighted):
     slots = _spec((B, L), jnp.int32, one_chip)
     w = _spec((B, L), jnp.float32, one_chip) if weighted else None
     hot = _spec((num_hot, D), jnp.float32, one_chip) if num_hot else None
-    compiled = jax.jit(
+    text = jax.jit(
         lambda c, s, w, h: fused_warm_lookup_pallas(
             c, s, w, h, opts=FusedLookupOpts())
-    ).lower(cache, slots, w, hot).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    ).lower(cache, slots, w, hot).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "%fused_embedding_bag." in text
 
 
 def test_bag_kernel_refuses_bf16_tables(one_chip):
