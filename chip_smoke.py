@@ -12,8 +12,8 @@ med_hot traffic made from `--seed`:
   A  `device` backend, backend="auto" (the Pallas bag kernel on a TPU),
      24 of the paper's 250 tables. The compiled engine must contain the
      kernel (`tpu_custom_call`); its logits and pooled rows must agree
-     with the same model on backend="xla". Run again with VMEM-pinned
-     hot rows.
+     with the same model on backend="xla". Run again with the tables
+     stored hot-first (`pinned_rows`).
   B  `tiered` backend with the fused warm-cache kernel, 8 tables on the
      host cold tier; pooled rows and logits against the dense float32
      reference (`embedding_bag_ref`).

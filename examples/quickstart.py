@@ -1,8 +1,8 @@
 """Quickstart: the paper's technique in 30 lines.
 
-Builds a small DLRM, profiles a trace, plans the hot-row cache (L2P
-analogue), and runs pinned + prefetch-pipelined embedding lookups that are
-bit-identical to the baseline.
+Builds a small embedding stage, profiles a trace, lets the planner size
+the bag kernel's slab (bags per grid step within VMEM), and runs the
+Pallas slab-gather lookups, which match the off-the-shelf XLA gather.
 
     PYTHONPATH=src python examples/quickstart.py
 """
@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import (EmbeddingBagCollection, EmbeddingStageConfig,
-                        make_pattern, plan_from_trace, plan_embedding_stage)
+                        make_pattern, plan_embedding_stage)
 
 ROWS, DIM, TABLES, POOL, BATCH = 20_000, 128, 4, 16, 64
 
@@ -21,9 +21,9 @@ trace = pattern.sample(BATCH, POOL, seed=0)
 
 # 2. the static profiling framework (paper §VII) picks the knobs
 report = plan_embedding_stage(trace, ROWS, DIM)
-print(f"planner: pin {report.pinned_rows} rows "
-      f"(covers {report.hot_coverage_at_k:.0%} of accesses), "
-      f"prefetch distance {report.prefetch_distance}")
+print(f"planner: {report.hotness_unique_pct:.1f}% unique rows, "
+      f"{report.batch_block} bags per grid step, "
+      f"{report.vmem_bytes / 2**20:.2f} MiB of VMEM")
 
 # 3. baseline collection (off-the-shelf XLA gather)
 base_cfg = EmbeddingStageConfig(num_tables=TABLES, rows=ROWS, dim=DIM,
@@ -34,21 +34,14 @@ indices = jnp.asarray(np.stack(
     [pattern.sample(BATCH, POOL, seed=t) for t in range(TABLES)], axis=1))
 baseline = ebc.apply(params, indices)
 
-# 4. optimized collection: hot-first reorder + pinned VMEM + deep pipeline
+# 4. the Pallas slab gather with the planner's knobs
 opt_cfg = EmbeddingStageConfig(
     num_tables=TABLES, rows=ROWS, dim=DIM, pooling=POOL,
     backend="pallas",                       # interpret=True on CPU
-    pinned_rows=report.pinned_rows,
-    prefetch_distance=report.prefetch_distance)
-plans = [plan_from_trace(np.asarray(indices)[:, t], ROWS, report.pinned_rows)
-         for t in range(TABLES)]
-ebc_opt = EmbeddingBagCollection(opt_cfg, plans)
-perm = jnp.asarray(np.stack([p.perm for p in plans]))
-opt_params = {"tables": jax.vmap(lambda t, p: jnp.take(t, p, axis=0))(
-    params["tables"], perm)}
-optimized = ebc_opt.apply(opt_params, indices)
+    batch_block=report.batch_block)
+optimized = EmbeddingBagCollection(opt_cfg).apply(params, indices)
 
 err = float(jnp.abs(optimized - baseline).max())
-print(f"pinned+pipelined output matches baseline: max|err| = {err:.2e}")
+print(f"slab-gather output matches baseline: max|err| = {err:.2e}")
 assert err < 1e-4
 print("OK")

@@ -86,9 +86,12 @@ class EmbeddingStageConfig:
     # 'sharded' (table-wise partition of the tiered store), or any
     # backend registered out of tree.
     storage: str = "device"
-    prefetch_distance: int = 8
     batch_block: int = 8
-    pinned_rows: int = 0           # K per table; paper: 60K rows across L2
+    # K per table stored hot-first (paper: 60K rows across L2). The bag
+    # kernel fetches every row from the table, so on the `device` backend
+    # this gains nothing, and remapping the indices costs time (76 ms a
+    # batch at 24 tables x 500,000 rows on a v5e)
+    pinned_rows: int = 0
     # pad the table stack so it divides the global device count -> each device
     # owns whole tables (table-parallel a2a plan; beyond-paper optimization,
     # see EXPERIMENTS.md SPerf iteration C1). 0 = no padding (row-wise plan).
@@ -102,12 +105,8 @@ class EmbeddingStageConfig:
         return self.num_tables * self.rows * self.dim * self.jnp_dtype.itemsize
 
     def kernel_opts(self) -> EmbeddingBagOpts:
-        return EmbeddingBagOpts(
-            prefetch_distance=self.prefetch_distance,
-            batch_block=self.batch_block,
-            num_hot=self.pinned_rows,
-            mode=self.combine,
-        )
+        return EmbeddingBagOpts(batch_block=self.batch_block,
+                                mode=self.combine)
 
 
 class EmbeddingBagCollection:

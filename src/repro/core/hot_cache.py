@@ -5,7 +5,10 @@ set-aside via `prefetch.global.L2::evict_last`. On TPU there is no shared LLC
 with residency control; VMEM is the software-managed fast memory. We therefore
 (1) profile a trace offline to find the top-K hot rows per table,
 (2) physically reorder each table hot-first, and
-(3) keep rows [0, K) resident in VMEM for the kernel's lifetime.
+(3) keep rows [0, K) in a device hot block (the `tiered` store's tier 0;
+    the fused kernel holds it in VMEM). The `device` backend's bag kernel
+    fetches them like any other row: its pace is one DMA start per row,
+    whatever the source.
 
 The remap is exact (a permutation), so lookups are bit-identical; only data
 placement changes. `periodic refresh` (paper §IV-C "update the pinned data
@@ -73,10 +76,3 @@ def identity_plan(num_rows: int, num_hot: int = 0) -> HotPlan:
     """No-reorder plan (e.g. tables already stored hot-first, or pinning off)."""
     ar = np.arange(num_rows, dtype=np.int64)
     return HotPlan(num_rows=num_rows, num_hot=num_hot, perm=ar, inv_perm=ar.copy())
-
-
-def vmem_budget_rows(dim: int, itemsize: int = 4,
-                     vmem_bytes: int = 96 * 2**20) -> int:
-    """How many rows fit in a VMEM pinning budget (default: leave headroom
-    out of v5e's 128MiB for pipeline buffers + output blocks)."""
-    return max(0, vmem_bytes // (dim * itemsize))

@@ -3,11 +3,14 @@
 The paper's recipe, adapted to TPU terms:
  (i)   memory-latency bound?   -> hotness metrics + arithmetic intensity
  (ii)  occupancy maximal?      -> batch_block grid coverage vs core count
- (iii) OptMT                   -> pick batch_block/pipeline depth within VMEM
- (iv)  still latency bound?    -> enable prefetching (distance sweep)
- (v)   high-reuse region?      -> pin top-K rows in VMEM (coverage threshold)
- (vi)  bandwidth headroom?     -> deepen the pipeline
- (vii) combine both
+ (iii) OptMT                   -> pick batch_block within VMEM (the bag
+                                  kernel's own footprint, kernel.vmem_bytes)
+ (iv)  still latency bound?    -> the bag kernel keeps a whole grid step's
+                                  rows in flight: no distance to pick
+ (v)   high-reuse region?      -> pinning: not for the bag kernel, whose
+                                  pace is one DMA start per row whatever
+                                  the source (the tiered store's hot tier
+                                  is sized by `plan_tier_capacities`)
 """
 from __future__ import annotations
 
@@ -16,18 +19,12 @@ import dataclasses
 import numpy as np
 
 from repro.core import access_patterns as ap
-
-# TPU v5e structural constants used for planning (see roofline/hw.py).
-VMEM_BYTES = 128 * 2**20
-VMEM_HEADROOM = 24 * 2**20     # output blocks, metadata, compiler slack
+from repro.kernels.embedding_bag import kernel as bag_kernel
 
 
 @dataclasses.dataclass(frozen=True)
 class EmbeddingPlanReport:
     hotness_unique_pct: float
-    hot_coverage_at_k: float      # fraction of accesses served by pinned rows
-    pinned_rows: int
-    prefetch_distance: int
     batch_block: int
     vmem_bytes: int
     latency_bound: bool
@@ -35,43 +32,29 @@ class EmbeddingPlanReport:
 
 
 def plan_embedding_stage(trace: np.ndarray, num_rows: int, dim: int,
-                         itemsize: int = 4,
-                         target_coverage: float = 0.5) -> EmbeddingPlanReport:
-    """Given an offline index trace for one table, pick the kernel knobs."""
+                         itemsize: int = 4) -> EmbeddingPlanReport:
+    """Given an offline index trace [N, L] for one table, pick the bag
+    kernel's knobs: the most bags per grid step, up to the default
+    `batch_block`, whose slabs fit the kernel's VMEM budget at this
+    pooling."""
     notes = []
     uniq = ap.unique_access_pct(trace, num_rows)
-    counts = np.bincount(trace.reshape(-1), minlength=num_rows)
-    order = np.argsort(-counts)
-    csum = np.cumsum(counts[order]) / max(1, trace.size)
+    pooling = int(trace.shape[-1])
 
     # (i) latency bound: gather of one row (dim*itemsize bytes) per 2*dim flops
     # -> arithmetic intensity ~ 2/itemsize flop/byte << ridge; always true.
     latency_bound = True
 
-    # (v) pinning: smallest K reaching target coverage, clamped to VMEM budget.
-    budget_rows = (VMEM_BYTES - VMEM_HEADROOM) // (dim * itemsize)
-    k_cov = int(np.searchsorted(csum, target_coverage) + 1)
-    if csum[-1] < target_coverage:
-        k_cov = num_rows
-    pinned = int(min(k_cov, budget_rows, num_rows))
-    coverage = float(csum[pinned - 1]) if pinned > 0 else 0.0
-    if coverage < 0.10:
-        notes.append("low reuse: pinning covers <10% of accesses; disabled")
-        pinned, coverage = 0, 0.0
-
-    # (iii/iv/vi) pipeline: deeper when cold fraction is high. One row DMA is
-    # dim*itemsize bytes; keep total buffer under 1MiB.
-    cold_frac = 1.0 - coverage
-    distance = int(np.clip(np.ceil(16 * cold_frac), 2, 16))
-    max_by_buf = max(1, (1 << 20) // (dim * itemsize))
-    distance = min(distance, max_by_buf)
-
-    batch_block = 8
-    vmem = (pinned + distance + batch_block) * dim * itemsize
+    # (iii) the slab's depth within the kernel's VMEM budget
+    opts = bag_kernel.EmbeddingBagOpts()
+    bb = bag_kernel.bags_per_step(opts, pooling, dim, itemsize)
+    if bb < opts.batch_block:
+        notes.append(f"bags of {pooling} rows: {bb} bags per grid step fit "
+                     f"VMEM, not {opts.batch_block}")
+    vmem = dataclasses.replace(opts, batch_block=bb).vmem_bytes(
+        pooling, dim, itemsize)
     return EmbeddingPlanReport(
-        hotness_unique_pct=uniq, hot_coverage_at_k=coverage,
-        pinned_rows=pinned, prefetch_distance=distance,
-        batch_block=batch_block, vmem_bytes=int(vmem),
+        hotness_unique_pct=uniq, batch_block=bb, vmem_bytes=int(vmem),
         latency_bound=latency_bound, notes=tuple(notes))
 
 

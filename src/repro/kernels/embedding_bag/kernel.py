@@ -1,37 +1,46 @@
-"""Pallas TPU embedding-bag kernel: gather-reduce with software prefetching
-and a VMEM-pinned hot-row cache.
+"""Pallas TPU embedding-bag kernel: a slab gather with whole-tile reduces.
 
-TPU adaptation of the paper's three mechanisms (see DESIGN.md §2):
+TPU adaptation of the paper's mechanisms (see DESIGN.md §2):
 
-* software prefetching (paper §IV-B)  ->  index-driven `pltpu.make_async_copy`
-  row DMAs from HBM into a rotating VMEM buffer, `prefetch_distance` rows in
-  flight. Indices live in SMEM so the scalar core computes DMA addresses ahead
-  of use — prefetches are 100% accurate, exactly as in the paper.
-* L2 pinning (paper §IV-C)  ->  the hottest `num_hot` rows (tables stored
-  hot-first, see core/hot_cache.py) are passed as a separate VMEM-resident
-  operand; hot lookups never touch HBM.
-* OptMT / occupancy (paper §III-C)  ->  `batch_block` (samples per grid step)
-  and `prefetch_distance` control grid parallelism and DMA concurrency; the
-  VMEM footprint of (pinned rows + pipeline buffers + output block) is the
-  analogue of the register budget.
+* software prefetching (paper §IV-B)  ->  each grid step's rows are
+  fetched into one VMEM *slab* `[batch_block, L_pad, D]` by index-driven
+  `pltpu.make_async_copy` row DMAs, issued back to back with no wait
+  between two starts. Indices live in SMEM so the scalar core computes
+  every DMA address ahead of use — prefetches are 100% accurate, exactly
+  as in the paper. The slab is double-buffered across grid steps: step b
+  issues block b+1's rows before it waits for and reduces block b's, so
+  a whole step's rows are in flight while the previous slab is summed.
+* L2 pinning (paper §IV-C) has no part here: the kernel's pace is the
+  scalar core's one DMA start per looked-up row, which a VMEM source
+  costs as much as an HBM one, so every row comes from the table (a
+  branch between the two sources made the kernel 1.8x slower on a v5e).
+  `EmbeddingStageConfig.pinned_rows` still stores tables hot-first.
+* OptMT / occupancy (paper §III-C)  ->  `batch_block` (bags per grid step)
+  sizes the slab; the VMEM footprint of (two slabs + output block) is the
+  analogue of the register budget, and the wrapper lowers the bags per
+  step from the shapes when it would not fit `VMEM_BUDGET`.
+
+The reduce is `sum(slab[s, :L], axis=0)` over whole (8, 128) tiles, eight
+rows per vector add; weights scale each slab row in place before the sum
+(a multiply fused into the reduce would contract to an FMA), and `mean`
+divides the finished sum.
 
 One launch serves a whole `[T, R, D]` table stack: the grid is
 (table, batch block), the stack stays in HBM (`memory_space=ANY`) and each
-row DMA addresses `table_ref.at[t, row]`. The indices, weights, hot block
-and output are blocked per table. (A `jax.vmap` over a single-table
-kernel cannot lower: Mosaic accepts an ANY-space operand only as one
-whole, unblocked array.)
-
-The pipeline is *flattened* over (sample, lookup) so row DMAs stream across
-bag boundaries with no per-sample drain bubble — a beyond-paper improvement
-(the paper's per-CUDA-thread pipeline restarts at each bag).
+row DMA addresses `table_ref.at[t, row]`. The indices, weights and output
+are blocked per table; the indices come twice, as block b and as block
+b+1, so that a step can issue its successor's rows. The batch axis
+carries the slab from one step to the next, so it is `arbitrary`; the
+table axis is `parallel` (a table's first step issues its own rows).
+(A `jax.vmap` over a single-table kernel cannot lower: Mosaic accepts an
+ANY-space operand only as one whole, unblocked array.)
 
 Layout notes (TPU): rows are [D] f32 with D a multiple of 128 preferred
 (lane dimension). Tables must be float32: a packed dtype (bf16) stores
 row pairs in one 32-bit word of an (8, 128) tile, and Mosaic refuses the
-kernel's single-row slices of such a layout ("cannot statically prove
-that index ... is a multiple of 8"). `embedding_bag_pallas` rejects such
-tables up front.
+kernel's single-row DMA destinations in such a layout ("cannot statically
+prove that index ... is a multiple of 8"). `embedding_bag_pallas` rejects
+such tables up front.
 """
 from __future__ import annotations
 
@@ -43,111 +52,135 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+#: VMEM the kernel's blocks may take: under v5e's 16 MiB default scoped
+#: limit, with room left for Mosaic's own scratch.
+VMEM_BUDGET = 12 * 2**20
+
+#: most DMA starts per iteration of the scalar issue loop: a bag of up to
+#: this many rows is issued as straight-line code (Mosaic lowers a
+#: `fori_loop` only fully unrolled or not at all, so the kernel unrolls
+#: by hand). On a v5e, a bag of 150 rows issued whole took 13.8 ns per
+#: row; in chunks of 64 rows, 17.3.
+UNROLL = 256
+
+SUBLANES = 8   # float32 rows per (8, 128) tile
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
 
 @dataclasses.dataclass(frozen=True)
 class EmbeddingBagOpts:
-    """Tuning knobs (paper-mechanism analogues)."""
+    """Tuning knobs (paper-mechanism analogues). The rows in flight are
+    not a knob: a grid step keeps all of its successor's rows (up to
+    batch_block x pooling) in flight."""
 
-    prefetch_distance: int = 8   # rows in flight (paper Fig. 9 sweep)
-    batch_block: int = 8         # samples per grid step (occupancy analogue)
-    num_hot: int = 0             # VMEM-pinned hot rows (L2P analogue); 0 = off
+    # most bags per grid step, the slab's depth (occupancy analogue);
+    # lowered to a divisor that fits VMEM_BUDGET
+    batch_block: int = 8
     mode: str = "sum"            # 'sum' | 'mean'
     interpret: bool = False      # CPU validation mode
 
-    def vmem_bytes(self, dim: int, itemsize: int = 4) -> int:
-        buf = self.prefetch_distance * dim * itemsize
-        hot = self.num_hot * dim * itemsize
-        out = self.batch_block * dim * itemsize
-        return buf + hot + out
+    def vmem_bytes(self, pooling: int, dim: int, itemsize: int = 4) -> int:
+        """VMEM of the bag kernel's blocks: two slabs, and the pipeline's
+        two buffers of the output block."""
+        row = dim * itemsize
+        slab = 2 * self.batch_block * _round_up(max(1, pooling), SUBLANES) * row
+        out = 2 * self.batch_block * row
+        return slab + out
 
 
-def _bag_kernel(idx_ref, w_ref, table_ref, hot_ref, out_ref, buf_ref, sem_ref,
-                *, pooling: int, distance: int, num_hot: int, mode: str,
-                has_weights: bool):
-    """One grid step (t, b): `batch_block` bags of table t, flattened
-    software pipeline.
+def bags_per_step(opts: EmbeddingBagOpts, pooling: int, dim: int,
+                  itemsize: int = 4) -> int:
+    """The largest divisor of `opts.batch_block` whose blocks fit
+    `VMEM_BUDGET` (1 if none does: the compiler then says what is over)."""
+    bb = opts.batch_block
+    for d in range(bb, 0, -1):
+        if bb % d == 0 and dataclasses.replace(opts, batch_block=d).vmem_bytes(
+                pooling, dim, itemsize) <= VMEM_BUDGET:
+            return d
+    return 1
 
-    idx_ref: SMEM [batch_block, pooling] int32 (hot-first remapped)
-    w_ref:   SMEM [batch_block, pooling] f32 or None
+
+def _bag_kernel(idx_ref, nxt_ref, w_ref, table_ref, out_ref, slab_ref,
+                sem_ref, *, pooling: int, mode: str):
+    """One grid step (t, b): issue block b+1's rows into one slab, then
+    wait for block b's slab and reduce it.
+
+    idx_ref:  SMEM [bb, L] int32, block b
+    nxt_ref:  SMEM [bb, L] int32, block b+1 (block b at the last step)
+    w_ref:    SMEM [bb, L] f32 or None
     table_ref: HBM [T, R, D] (memory_space=ANY; manual DMA only)
-    hot_ref: VMEM [num_hot, D] or None
-    out_ref: VMEM [batch_block, D]
-    buf_ref: VMEM scratch [distance, D]
-    sem_ref: DMA semaphores [distance]
+    out_ref:  VMEM [bb, D]
+    slab_ref: VMEM scratch [2, bb, L_pad, D], one slab per block in flight
+    sem_ref:  DMA semaphores [2], one per slab; every row of a slab
+              signals it with one row's bytes
     """
     tbl = pl.program_id(0)
+    blk = pl.program_id(1)
     bb = out_ref.shape[0]
-    dim = out_ref.shape[1]
-    total = bb * pooling
+    slot = jax.lax.rem(blk, 2)
     f32 = jnp.float32
 
-    def row_dma(row, slot):
-        return pltpu.make_async_copy(
-            table_ref.at[tbl, row], buf_ref.at[slot],
-            sem_ref.at[slot])
+    def issue(ids_ref, dst):
+        """Start the DMA of every row of one block into slab `dst`, with
+        no wait and no vector work between two starts."""
+        sem = sem_ref.at[dst]
 
-    def start_fetch(t):
-        """Begin the HBM->VMEM row DMA for flat step t (cold rows only)."""
-        row = idx_ref[t // pooling, t % pooling]
+        def bag(s, c):
+            def row(i):
+                pltpu.make_async_copy(table_ref.at[tbl, ids_ref[s, i]],
+                                      slab_ref.at[dst, s, i], sem).start()
 
-        @pl.when(row >= num_hot)
-        def _():
-            row_dma(row, jax.lax.rem(t, distance)).start()
+            def chunk(j, c):
+                for u in range(UNROLL):
+                    row(j * UNROLL + u)
+                return c
+            if pooling >= UNROLL:
+                jax.lax.fori_loop(0, pooling // UNROLL, chunk, 0)
+            for i in range(pooling - pooling % UNROLL, pooling):
+                row(i)
+            return c
+        jax.lax.fori_loop(0, bb, bag, 0)
 
-    # Prologue: fill the pipeline `distance` deep (paper: prefetch distance).
-    for j in range(min(distance, total)):
-        start_fetch(j)
+    @pl.when(blk == 0)
+    def _():
+        issue(idx_ref, slot)
 
-    def body(t, carry):
-        acc, wsum = carry
-        s = t // pooling
-        i = t % pooling
-        row = idx_ref[s, i]
-        slot = jax.lax.rem(t, distance)
-        is_hot = row < num_hot
+    @pl.when(blk + 1 < pl.num_programs(1))
+    def _():
+        issue(nxt_ref, 1 - slot)
 
-        # Reset accumulator at bag start.
-        acc = jnp.where(i == 0, jnp.zeros_like(acc), acc)
-        wsum = jnp.where(i == 0, jnp.zeros_like(wsum), wsum)
+    # Wait for block b's slab, every row issued before the first wait: a
+    # wait takes one bag's bytes (L rows) off the slab's semaphore, which
+    # counts the bytes landed whatever bag they belong to. The descriptor
+    # only sizes the wait; it moves nothing, and it names the slab alone,
+    # so that a table of fewer than L rows sizes it the same.
+    bag_rows = slab_ref.at[slot, 0, pl.ds(0, pooling)]
+    one_bag = pltpu.make_async_copy(bag_rows, bag_rows, sem_ref.at[slot])
+    for _ in range(bb):
+        one_bag.wait()
 
-        # Consume: wait on the DMA for cold rows; hot rows read VMEM directly.
-        @pl.when(jnp.logical_not(is_hot))
-        def _():
-            row_dma(row, slot).wait()
-
-        row_vec = buf_ref[pl.ds(slot, 1), :]                   # [1, D]
-        if num_hot > 0:
-            safe = jnp.minimum(row, num_hot - 1)
-            row_vec = jnp.where(is_hot, hot_ref[pl.ds(safe, 1), :], row_vec)
-        row_vec = row_vec.astype(f32)
-
-        if has_weights:
-            w = w_ref[s, i].astype(f32)
-            acc = acc + row_vec[0] * w
-            wsum = wsum + w
-        else:
-            acc = acc + row_vec[0]
-            wsum = wsum + 1.0
-
-        # Keep the pipeline full: prefetch row t+distance.
-        @pl.when(t + distance < total)
-        def _():
-            start_fetch(t + distance)
-
-        # Bag boundary: reduce and store.
-        @pl.when(i == pooling - 1)
-        def _():
-            if mode == "mean":
-                denom = jnp.maximum(wsum, 1e-9) if has_weights else f32(pooling)
-                val = acc / denom
+    def reduce(s, c):
+        if w_ref is not None:
+            def scale(i, c):
+                # in place: the product is rounded before the sum reads it
+                slab_ref[slot, s, pl.ds(i, 1), :] = (
+                    slab_ref[slot, s, pl.ds(i, 1), :] * w_ref[s, i])
+                return c
+            jax.lax.fori_loop(0, pooling, scale, 0)
+        val = jnp.sum(slab_ref[slot, s, pl.ds(0, pooling), :], axis=0)
+        if mode == "mean":
+            if w_ref is not None:
+                wsum = jax.lax.fori_loop(
+                    0, pooling, lambda i, a: a + w_ref[s, i], f32(0.0))
+                val = val / jnp.maximum(wsum, 1e-9)
             else:
-                val = acc
-            out_ref[pl.ds(s, 1), :] = val[None, :].astype(out_ref.dtype)
-
-        return acc, wsum
-
-    init = (jnp.zeros((dim,), f32), f32(0.0))
-    jax.lax.fori_loop(0, total, body, init)
+                val = val / f32(pooling)
+        out_ref[pl.ds(s, 1), :] = val[None, :]
+        return c
+    jax.lax.fori_loop(0, bb, reduce, 0)
 
 
 def embedding_bag_pallas(tables: jnp.ndarray, indices: jnp.ndarray,
@@ -155,9 +188,7 @@ def embedding_bag_pallas(tables: jnp.ndarray, indices: jnp.ndarray,
                          opts: EmbeddingBagOpts = EmbeddingBagOpts()) -> jnp.ndarray:
     """Fixed-pooling embedding bag over a table stack, one Pallas launch.
 
-    tables:  [T, R, D] (if opts.num_hot > 0, each table must already be
-             hot-first ordered and `indices` remapped — see
-             core/hot_cache.HotPlan)
+    tables:  [T, R, D]
     indices: [T, B, L] int32, B % opts.batch_block == 0 (ops.py pads)
     weights: [T, B, L] or None
     returns: [T, B, D] float32
@@ -169,55 +200,57 @@ def embedding_bag_pallas(tables: jnp.ndarray, indices: jnp.ndarray,
             f"packed dtype (use backend='xla' for {tables.dtype} tables)")
     num_tables, batch, pooling = indices.shape
     dim = tables.shape[2]
-    bb = opts.batch_block
-    if batch % bb:
-        raise ValueError(f"batch {batch} not divisible by batch_block {bb}")
-    distance = max(1, min(opts.prefetch_distance, bb * pooling))
-    num_hot = int(min(opts.num_hot, tables.shape[1]))
+    if batch % opts.batch_block:
+        raise ValueError(f"batch {batch} not divisible by batch_block "
+                         f"{opts.batch_block}")
+    bb = bags_per_step(opts, pooling, dim, tables.dtype.itemsize)
+    blocks = batch // bb
     has_weights = weights is not None
 
-    kernel = functools.partial(
-        _bag_kernel, pooling=pooling, distance=distance, num_hot=num_hot,
-        mode=opts.mode, has_weights=has_weights)
+    kernel = functools.partial(_bag_kernel, pooling=pooling, mode=opts.mode)
 
-    # per-table blocks; `None` squeezes the table axis out of the kernel view
-    bag_spec = pl.BlockSpec((None, bb, pooling), lambda t, b: (t, b, 0),
+    # Blocks of `bb` bags, as [T, B / bb, bb, ...] views: the last two
+    # dims of a block are then whole, so `bb` need not be a multiple of 8
+    # (the views are free when it is). `None` squeezes an axis out of the
+    # kernel view.
+    def bag_spec(index_map):
+        return pl.BlockSpec((None, None, bb, pooling), index_map,
                             memory_space=pltpu.SMEM)
-    in_specs = [
-        bag_spec,
-        bag_spec if has_weights else None,
-        pl.BlockSpec(memory_space=pl.ANY),  # table stack stays in HBM
-        (pl.BlockSpec((None, num_hot, dim), lambda t, b: (t, 0, 0))
-         if num_hot else None),
-    ]
-    inputs = [indices.astype(jnp.int32),
-              weights.astype(jnp.float32) if has_weights else None,
-              tables,
-              tables[:, :num_hot] if num_hot else None]
+    this_block = bag_spec(lambda t, b: (t, b, 0, 0))
+    next_block = bag_spec(lambda t, b: (t, jnp.minimum(b + 1, blocks - 1),
+                                        0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)  # table stack stays in HBM
+    blocked = (num_tables, blocks, bb, pooling)
+    idx = indices.astype(jnp.int32).reshape(blocked)
+    if has_weights:
+        in_specs = [this_block, next_block, this_block, hbm]
+        operands = (idx, idx, weights.astype(jnp.float32).reshape(blocked),
+                    tables)
+        body = kernel
+    else:
+        in_specs = [this_block, next_block, hbm]
+        operands = (idx, idx, tables)
 
-    # Drop the unused operand slots (w/ matching kernel signature via wrapper).
-    live = [i for i, s in enumerate(in_specs) if s is not None]
-
-    def kernel_wrapper(*refs):
-        args = [None, None, None, None]
-        for j, i in enumerate(live):
-            args[i] = refs[j]
-        _out, _buf, _sem = refs[len(live):]
-        kernel(args[0], args[1], args[2], args[3], _out, _buf, _sem)
+        def body(idx_ref, nxt_ref, *refs):
+            kernel(idx_ref, nxt_ref, None, *refs)
 
     return pl.pallas_call(
-        kernel_wrapper,
-        grid=(num_tables, batch // bb),
-        in_specs=[in_specs[i] for i in live],
-        out_specs=pl.BlockSpec((None, bb, dim), lambda t, b: (t, b, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_tables, batch, dim), tables.dtype),
+        body,
+        grid=(num_tables, blocks),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, None, bb, dim),
+                               lambda t, b: (t, b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((num_tables, blocks, bb, dim),
+                                       tables.dtype),
         scratch_shapes=[
-            pltpu.VMEM((distance, dim), tables.dtype),  # DMA dst == src
-            pltpu.SemaphoreType.DMA((distance,)),
+            # DMA dst == src dtype
+            pltpu.VMEM((2, bb, _round_up(pooling, SUBLANES), dim),
+                       tables.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=opts.interpret,
         name="embedding_bag",
-    )(*[inputs[i] for i in live])
+    )(*operands).reshape(num_tables, batch, dim)

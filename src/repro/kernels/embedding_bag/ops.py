@@ -43,11 +43,7 @@ def embedding_bag_stacked(tables: jnp.ndarray, indices: jnp.ndarray,
                           mode: str = "sum",
                           opts: EmbeddingBagOpts | None = None) -> jnp.ndarray:
     """The Pallas kernel over a table stack, one launch:
-    [T,R,D] x [T,B,L] -> [T,B,D].
-
-    When `opts.num_hot > 0` the caller is responsible for hot-first table
-    order + remapped indices (core.embedding.EmbeddingBagCollection does this).
-    """
+    [T,R,D] x [T,B,L] -> [T,B,D]."""
     opts = opts or EmbeddingBagOpts()
     opts = dataclasses.replace(opts, mode=mode,
                                interpret=opts.interpret or not _on_tpu())

@@ -4,12 +4,13 @@ The seed behaviour (every table fits on device), re-homed behind the
 `EmbeddingStorage` protocol. `lookup()` is the jit-traceable dense path:
 hot-first remap, optional table-stack padding for whole-table sharding,
 then either a vmapped `jnp.take` (XLA baseline) or the Pallas
-prefetch-pipelined embedding-bag kernel, and the shared pooling reduction.
+slab-gather embedding-bag kernel, and the shared pooling reduction.
 
 No staging, no refresh: with everything resident there is nothing to
-overlap or re-pin at the storage level (the paper's in-kernel prefetch and
-VMEM pinning live inside the Pallas kernel itself, selected by
-`EmbeddingStageConfig.backend`/`pinned_rows`).
+overlap or re-pin at the storage level (the paper's in-kernel prefetch
+lives inside the Pallas kernel itself, selected by
+`EmbeddingStageConfig.backend`; `pinned_rows` stores the tables
+hot-first, and the kernel fetches their rows like any others).
 """
 from __future__ import annotations
 

@@ -25,6 +25,7 @@ from repro.core import EmbeddingBagCollection, EmbeddingStageConfig
 from repro.kernels.embedding_bag import (EmbeddingBagOpts, FusedLookupOpts,
                                          embedding_bag_pallas,
                                          fused_warm_lookup_pallas)
+from repro.kernels.embedding_bag import kernel as bag_kernel
 from repro.kernels.embedding_bag import ops as bag_ops
 
 T, R, D, B, L = 2, 500_000, 128, 2048, 150
@@ -65,10 +66,16 @@ def _spec(shape, dtype, sharding):
 def test_device_backend_lookup_compiles(one_chip, monkeypatch, pinned,
                                         weighted):
     """The `device` backend's lookup as the served path jits it: hot-first
-    remap, then ONE stacked-table kernel launch over [T, R, D]."""
+    remap, then ONE stacked-table kernel launch over [T, R, D]. Its blocks
+    (two slabs of 8 bags x 152 rows, output) stay under the kernel's VMEM
+    budget, itself under v5e's 16 MiB scoped limit, with no cut to the
+    bags per step."""
     monkeypatch.setattr(bag_ops, "_on_tpu", lambda: True)
     cfg = EmbeddingStageConfig(num_tables=T, rows=R, dim=D, pooling=L,
                                backend="pallas", pinned_rows=pinned)
+    opts = cfg.kernel_opts()
+    assert opts.vmem_bytes(L, D) <= bag_kernel.VMEM_BUDGET < 16 * 2**20
+    assert bag_kernel.bags_per_step(opts, L, D) == opts.batch_block
     ebc = EmbeddingBagCollection(cfg)
     params = {"tables": _spec((T, R, D), jnp.float32, one_chip)}
     idx = _spec((B, T, L), jnp.int32, one_chip)
@@ -93,6 +100,53 @@ def test_fused_kernel_compiles_at_serving_batch(one_chip, num_hot, weighted):
     ).lower(cache, slots, w, hot).compile().as_text()
     assert "tpu_custom_call" in text
     assert "%fused_embedding_bag." in text
+
+
+@pytest.mark.parametrize("mode", ["sum", "mean"])
+def test_bag_kernel_weighted_modes_compile(one_chip, mode):
+    """The weighted paths the served config does not take: the in-place
+    scale of each slab row, and the weighted mean's divide."""
+    tables = _spec((T, R, D), jnp.float32, one_chip)
+    idx = _spec((T, B, L), jnp.int32, one_chip)
+    w = _spec((T, B, L), jnp.float32, one_chip)
+    opts = EmbeddingBagOpts(mode=mode)
+    text = jax.jit(lambda t, i, w: embedding_bag_pallas(t, i, w, opts=opts)
+                   ).lower(tables, idx, w).compile().as_text()
+    assert "%embedding_bag." in text
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+def test_bag_kernel_compiles_for_tables_shorter_than_a_bag(one_chip,
+                                                           weighted):
+    """Tables of 64 rows under bags of 150: what sizes a slab's waits
+    must not slice the table."""
+    tables = _spec((T, 64, D), jnp.float32, one_chip)
+    idx = _spec((T, B, L), jnp.int32, one_chip)
+    w = _spec((T, B, L), jnp.float32, one_chip) if weighted else None
+    text = jax.jit(lambda t, i, w: embedding_bag_pallas(t, i, w)
+                   ).lower(tables, idx, w).compile().as_text()
+    assert "%embedding_bag." in text
+
+
+@pytest.mark.parametrize("budget", ["kernel", "none"])
+def test_bag_kernel_slab_fits_scoped_vmem(one_chip, monkeypatch, budget):
+    """Bags of 4,000 rows: two slabs of 8 bags would take 31 MiB of VMEM.
+    The wrapper lowers the bags per step from the shape (to 2), and the
+    kernel compiles; with no budget it keeps 8 and Mosaic refuses it."""
+    if budget == "none":
+        monkeypatch.setattr(bag_kernel, "VMEM_BUDGET", 2**40)
+    long_bags = 4000
+    opts = EmbeddingBagOpts()
+    tables = _spec((T, R, D), jnp.float32, one_chip)
+    idx = _spec((T, B, long_bags), jnp.int32, one_chip)
+    lowered = jax.jit(lambda t, i: embedding_bag_pallas(t, i, opts=opts)
+                      ).lower(tables, idx)
+    if budget == "none":
+        with pytest.raises(Exception, match="vmem"):
+            lowered.compile()
+        return
+    assert bag_kernel.bags_per_step(opts, long_bags, D) == 2
+    assert "%embedding_bag." in lowered.compile().as_text()
 
 
 def test_bag_kernel_refuses_bf16_tables(one_chip):

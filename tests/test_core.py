@@ -93,19 +93,28 @@ def test_hot_plan_coverage_matches_trace():
 
 
 def test_planner_report():
+    """The planner sizes the bag kernel's slab from the trace's pooling
+    with the kernel's own VMEM model, and recommends no pinning: the
+    kernel fetches every row from the table."""
+    from repro.kernels.embedding_bag import kernel as bag_kernel
     pat = make_pattern("high_hot", 4096, seed=1)
     trace = pat.sample(128, 20)
     rep = plan_embedding_stage(trace, 4096, dim=128)
     assert rep.latency_bound
-    assert rep.pinned_rows > 0
-    assert 2 <= rep.prefetch_distance <= 16
-    assert rep.hot_coverage_at_k > 0.4
+    assert rep.batch_block == 8 and rep.notes == ()
+    assert rep.vmem_bytes == bag_kernel.EmbeddingBagOpts().vmem_bytes(20, 128)
+    assert not hasattr(rep, "pinned_rows")
 
     flat = make_pattern("random", 4096, seed=1).sample(128, 20)
     rep2 = plan_embedding_stage(flat, 4096, dim=128)
-    # a flat trace needs far more pinned rows than a hot one for the same
-    # coverage target
-    assert rep2.pinned_rows > 5 * rep.pinned_rows
+    # a flat trace touches far more distinct rows than a hot one
+    assert rep2.hotness_unique_pct > 2 * rep.hotness_unique_pct
+
+    # bags of 4,000 rows: two slabs of 8 would overflow the budget
+    long_bags = make_pattern("random", 4096, seed=1).sample(4, 4000)
+    rep3 = plan_embedding_stage(long_bags, 4096, dim=128)
+    assert rep3.batch_block == 2 and len(rep3.notes) == 1
+    assert rep3.vmem_bytes <= bag_kernel.VMEM_BUDGET
 
 
 def test_embedding_collection_pinned_equals_baseline():
@@ -119,7 +128,7 @@ def test_embedding_collection_pinned_equals_baseline():
 
     cfgp = EmbeddingStageConfig(num_tables=4, rows=256, dim=32, pooling=6,
                                 backend="pallas", pinned_rows=32,
-                                prefetch_distance=4, batch_block=4)
+                                batch_block=4)
     plans = [plan_from_trace(idx[:, t], 256, 32) for t in range(4)]
     ebcp = EmbeddingBagCollection(cfgp, plans)
     perm = jnp.asarray(np.stack([pl.perm for pl in plans]))
