@@ -22,17 +22,17 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from bench import generator, run  # noqa: E402
-from bench.references import dlrm  # noqa: E402
+from bench import generator, run, work  # noqa: E402
 
 
 def readings(cfg: dict, traffic: dict, seed: int, batches: int) -> dict:
     made = generator.make_traffic(cfg, traffic, seed, batches, cfg["batch"])
+    ref = work.reference(cfg)
     sel = np.arange(len(made))
     want_pooled = "pooled_gap" in cfg["correct"]
-    ref_l, ref_p = dlrm.reference(seed, cfg, made.indices, made.dense, sel,
+    ref_l, ref_p = ref.reference(seed, cfg, made.indices, made.dense, sel,
                                   want_pooled=want_pooled)
-    ctl_l, ctl_p = dlrm.reference(seed, cfg, made.indices, made.dense, sel,
+    ctl_l, ctl_p = ref.reference(seed, cfg, made.indices, made.dense, sel,
                                   dtype=jnp.bfloat16,
                                   want_pooled=want_pooled)
     out = {"logit_gap": run.gap(ctl_l, ref_l)}

@@ -7,9 +7,21 @@ import jax
 import numpy as np
 
 
+def _frozen(value):
+    return tuple(value) if isinstance(value, list) else value
+
+
 def model_config(cfg: dict):
+    """The program's `DLRMConfig` for a configuration file. Lists (the
+    towers; `rows` and `pooling` where they are given per table) go to
+    the program as tuples. The optional objects `model_args` and
+    `embedding_args` hold further keyword arguments of `DLRMConfig` and
+    `EmbeddingStageConfig`, for fields the keys below do not name."""
     from repro.core.embedding import EmbeddingStageConfig
     from repro.models.dlrm import DLRMConfig
+
+    def extra(name):
+        return {k: _frozen(v) for k, v in cfg.get(name, {}).items()}
     return DLRMConfig(
         dense_features=cfg["dense_features"],
         bottom_mlp=tuple(cfg["bottom_mlp"]),
@@ -17,11 +29,13 @@ def model_config(cfg: dict):
         interaction=cfg["interaction"],
         dtype=cfg["dtype"],
         embedding=EmbeddingStageConfig(
-            num_tables=cfg["num_tables"], rows=cfg["rows"], dim=cfg["dim"],
-            pooling=cfg["pooling"], dtype=cfg["dtype"],
-            combine=cfg["combine"], backend=cfg["backend"],
-            storage=cfg["storage"],
-            shard_pad_tables=cfg["shard_pad_tables"]))
+            num_tables=cfg["num_tables"], rows=_frozen(cfg["rows"]),
+            dim=cfg["dim"], pooling=_frozen(cfg["pooling"]),
+            dtype=cfg["dtype"], combine=cfg["combine"],
+            backend=cfg["backend"], storage=cfg["storage"],
+            shard_pad_tables=cfg["shard_pad_tables"],
+            **extra("embedding_args")),
+        **extra("model_args"))
 
 
 class Deployment:

@@ -14,6 +14,21 @@ bits, the CDF is scaled to 2**32, and the first rank whose CDF reaches
 `u` is found from a bucket table over the top bits of `u` followed by a
 few comparisons — the same answer as a binary search over the CDF, at
 the cost of a handful of gathers per draw instead of twenty.
+
+Geometry per table. A configuration's `rows` and `pooling` are each one
+int for every table or a list of `num_tables` ints; a traffic file's
+`hotness` is one name of `HOTNESS_ALPHA` or a list of `num_tables`
+names. Each table is drawn from its own rows, exponent and bag size.
+The indices of N queries are laid out as follows:
+
+- `pooling` an int L: `[N, T, L]` int32, table t's bag in `[:, t]`.
+- `pooling` a list: `[N, sum(pooling)]` int32, table t's bag in columns
+  `off[t]:off[t+1]`, `off = cumsum([0] + pooling)`; no bag is padded.
+
+Where every table has the same rows, bag size and hotness, one jitted
+draw covers the stack (`_draw_batch`), and the ids are the same for a
+seed whichever way the geometry is spelled; otherwise each table is
+drawn on its own key (`_draw_tables`).
 """
 from __future__ import annotations
 
@@ -22,6 +37,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import work
 
 #: Zipf exponent per Table III hotness level (0.0: uniform).
 HOTNESS_ALPHA = {
@@ -73,6 +90,16 @@ def _permutations(key, *, num_tables: int, rows: int) -> jax.Array:
         lambda k: jax.random.permutation(k, rows).astype(jnp.int32))(keys)
 
 
+def _rank(u, cdf, bucket, shift: int, span: int):
+    """The first rank whose fixed-point CDF reaches each draw `u`."""
+    lo = bucket[(u >> shift).astype(jnp.int32)]
+    rank = lo
+    for j in range(span):
+        rank = rank + (cdf[jnp.minimum(lo + j, cdf.shape[0] - 1)]
+                       < u).astype(jnp.int32)
+    return rank
+
+
 @functools.partial(jax.jit, static_argnames=(
     "batch", "pooling", "dense_features", "shift", "span"))
 def _draw_batch(key, cdf, bucket, perms, *, batch: int, pooling: int,
@@ -80,20 +107,40 @@ def _draw_batch(key, cdf, bucket, perms, *, batch: int, pooling: int,
     num_tables, rows = perms.shape
     k_rows, k_dense = jax.random.split(key)
     u = jax.random.bits(k_rows, (batch, num_tables, pooling), jnp.uint32)
-    lo = bucket[(u >> shift).astype(jnp.int32)]
-    rank = lo
-    for j in range(span):
-        rank = rank + (cdf[jnp.minimum(lo + j, rows - 1)] < u).astype(
-            jnp.int32)
+    rank = _rank(u, cdf, bucket, shift, span)
     table = jnp.arange(num_tables, dtype=jnp.int32)[None, :, None]
     ids = perms.reshape(-1)[table * rows + rank]
     dense = jax.random.normal(k_dense, (batch, dense_features), jnp.float32)
     return ids, dense
 
 
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _table_permutations(key, *, rows: tuple) -> tuple:
+    return tuple(jax.random.permutation(jax.random.fold_in(key, t),
+                                        r).astype(jnp.int32)
+                 for t, r in enumerate(rows))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "zipf_of", "pooling", "shifts", "spans", "batch", "dense_features"))
+def _draw_tables(key, cdfs, buckets, perms, *, zipf_of: tuple,
+                 pooling: tuple, shifts: tuple, spans: tuple, batch: int,
+                 dense_features: int):
+    """Table t's bags from its own key, Zipf table `zipf_of[t]` and
+    permutation, joined into the flat [batch, sum(pooling)] layout."""
+    k_rows, k_dense = jax.random.split(key)
+    out = []
+    for t, (z, size, perm) in enumerate(zip(zipf_of, pooling, perms)):
+        u = jax.random.bits(jax.random.fold_in(k_rows, t), (batch, size),
+                            jnp.uint32)
+        out.append(perm[_rank(u, cdfs[z], buckets[z], shifts[z], spans[z])])
+    dense = jax.random.normal(k_dense, (batch, dense_features), jnp.float32)
+    return jnp.concatenate(out, axis=1), dense
+
+
 class Traffic:
-    """The queries of one run: `indices` [N, T, L] int32 and `dense`
-    [N, F] float32, on the host."""
+    """The queries of one run: `indices` int32 in the layout above and
+    `dense` [N, F] float32, on the host."""
 
     def __init__(self, indices, dense):
         self.indices = indices
@@ -103,33 +150,55 @@ class Traffic:
         return len(self.indices)
 
 
+def _drawer(cfg: dict, traffic: dict, key, batch: int):
+    """The jitted draw of one batch from its key: (ids, dense)."""
+    tables, features = cfg["num_tables"], cfg["dense_features"]
+    rows, pooling = work.table_rows(cfg), work.table_pooling(cfg)
+    alphas = [HOTNESS_ALPHA[h] for h in
+              work.per_table(traffic["hotness"], tables, "hotness")]
+    if len(set(rows)) == len(set(pooling)) == len(set(alphas)) == 1:
+        cdf, bucket, shift, span = zipf_tables(alphas[0], rows[0])
+        perms = _permutations(jax.random.fold_in(key, 0),
+                              num_tables=tables, rows=rows[0])
+        return functools.partial(
+            _draw_batch, cdf=jnp.asarray(cdf), bucket=jnp.asarray(bucket),
+            perms=perms, batch=batch, pooling=pooling[0],
+            dense_features=features, shift=shift, span=span)
+    zipf: dict = {}                  # (alpha, rows) -> its tables' index
+    for pair in zip(alphas, rows):
+        zipf.setdefault(pair, len(zipf))
+    made = [zipf_tables(a, r) for a, r in zipf]
+    perms = _table_permutations(jax.random.fold_in(key, 0), rows=tuple(rows))
+    return functools.partial(
+        _draw_tables, cdfs=tuple(jnp.asarray(m[0]) for m in made),
+        buckets=tuple(jnp.asarray(m[1]) for m in made), perms=perms,
+        zipf_of=tuple(zipf[p] for p in zip(alphas, rows)),
+        pooling=tuple(pooling), shifts=tuple(m[2] for m in made),
+        spans=tuple(m[3] for m in made), batch=batch,
+        dense_features=features)
+
+
 def make_traffic(cfg: dict, traffic: dict, seed: int, batches: int,
                  batch: int) -> Traffic:
     """Draw `batches` full batches of queries on the device and bring
     them to the host. Batch i is the same for a seed whatever `batches`
     is, so a longer pool only adds batches."""
-    alpha = HOTNESS_ALPHA[traffic["hotness"]]
-    rows, tables = cfg["rows"], cfg["num_tables"]
-    pooling, features = cfg["pooling"], cfg["dense_features"]
-    cdf, bucket, shift, span = zipf_tables(alpha, rows)
     key = seed_key(seed, 2)
-    perms = _permutations(jax.random.fold_in(key, 0), num_tables=tables,
-                          rows=rows)
-    cdf, bucket = jnp.asarray(cdf), jnp.asarray(bucket)
-    indices = np.empty((batches * batch, tables, pooling), np.int32)
-    dense = np.empty((batches * batch, features), np.float32)
-    draw = functools.partial(_draw_batch, cdf=cdf, bucket=bucket,
-                             perms=perms, batch=batch, pooling=pooling,
-                             dense_features=features, shift=shift,
-                             span=span)
+    draw = _drawer(cfg, traffic, key, batch)
+    width = sum(work.table_pooling(cfg))
+    indices = np.empty((batches * batch, width), np.int32)
+    dense = np.empty((batches * batch, cfg["dense_features"]), np.float32)
     pending = draw(jax.random.fold_in(key, 1))
     for i in range(batches):
         nxt = (draw(jax.random.fold_in(key, i + 2))
                if i + 1 < batches else None)
         ids, dn = jax.device_get(pending)
-        indices[i * batch:(i + 1) * batch] = ids
+        indices[i * batch:(i + 1) * batch] = ids.reshape(batch, width)
         dense[i * batch:(i + 1) * batch] = dn
         pending = nxt
+    if np.ndim(cfg["pooling"]) == 0:
+        indices = indices.reshape(len(indices), cfg["num_tables"],
+                                  cfg["pooling"])
     return Traffic(indices, dense)
 
 

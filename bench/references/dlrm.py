@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench.generator import seed_key
+from bench.work import table_pooling
 
 CHUNK = 2048                  # queries per block of the reference
 
@@ -37,6 +38,27 @@ def _mlp_dims(cfg: dict) -> tuple:
     top_in = cfg["bottom_mlp"][-1] + f * (f - 1) // 2
     return ((cfg["dense_features"], *cfg["bottom_mlp"]),
             (top_in, *cfg["top_mlp"]))
+
+
+def mlp_shapes(cfg: dict) -> list[tuple[int, int]]:
+    """(fan_in, fan_out) of every layer, bottom tower then top."""
+    return [io for dims in _mlp_dims(cfg) for io in zip(dims[:-1], dims[1:])]
+
+
+def step_flops(cfg: dict, batch: int) -> int:
+    """Floating-point operations of one forward over `batch` queries: the
+    bag additions, both MLP towers (multiply and add), and the full
+    Gram matrix of the dot interaction."""
+    t, d = cfg["num_tables"], cfg["dim"]
+    bags = batch * sum(table_pooling(cfg)) * d
+    mlps = sum(2 * batch * i * o for i, o in mlp_shapes(cfg))
+    gram = 2 * batch * (t + 1) ** 2 * d
+    return int(bags + mlps + gram)
+
+
+def weight_count(cfg: dict) -> int:
+    """Weights and biases of both MLP towers."""
+    return sum(i * o + o for i, o in mlp_shapes(cfg))
 
 
 def _tower(key, dims) -> dict:

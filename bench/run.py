@@ -28,7 +28,6 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
-import importlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
@@ -142,7 +141,8 @@ class Run:
                 pad = np.zeros((self.batch - len(idx),) + idx.shape[1:],
                                idx.dtype)
                 idx = np.concatenate([idx, pad])
-            self._distinct[key] = work.distinct_rows(idx, self.cfg["rows"])
+            self._distinct[key] = work.distinct_rows(
+                idx, self.cfg["rows"], self.cfg["pooling"])
         return self._distinct[key]
 
 
@@ -170,7 +170,7 @@ def check(cfg: dict, seed: int, window: loops.Window, indices, dense,
     """Each compared number with its limit. Every answer served, in the
     window and after it, against the reference's; pooled rows too where
     the storage hands them to the engine."""
-    ref_mod = importlib.import_module(f"bench.references.{cfg['reference']}")
+    ref_mod = work.reference(cfg)
     batches = window.batches + window.drained
     qids = np.concatenate([b.qids for b in batches])
     uniq, inv = np.unique(qids, return_inverse=True)
@@ -187,6 +187,13 @@ def check(cfg: dict, seed: int, window: loops.Window, indices, dense,
     return {k: {"value": v, "limit": limits[k]} for k, v in numbers.items()}
 
 
+def free(tree) -> None:
+    """Delete every device array of `tree` not deleted yet."""
+    for leaf in jax.tree.leaves(tree):
+        if not leaf.is_deleted():
+            leaf.delete()
+
+
 def make_queries(traffic: generator.Traffic) -> list:
     from repro.serving import Query
     return [Query(qid=i, dense=traffic.dense[i], indices=traffic.indices[i])
@@ -199,7 +206,7 @@ def run_cell(cfg: dict, traffic: dict, seed: int, seconds: float,
     """One run of a cell; returns the result object. `fault`, for the
     harness's own tests, breaks the engine under the window."""
     from bench.deploy import Deployment
-    ref_mod = importlib.import_module(f"bench.references.{cfg['reference']}")
+    ref_mod = work.reference(cfg)
     dev = jax.devices()[0]
     batch = cfg["batch"]
     warm = cfg["prewarm_batches"]
@@ -218,14 +225,15 @@ def run_cell(cfg: dict, traffic: dict, seed: int, seconds: float,
     w_idx = made.indices[warm * batch:warm * batch + n_win]
     w_dense = made.dense[warm * batch:warm * batch + n_win]
     warm_idx = made.indices[:warm * batch]
-    uniq = work.distinct_rows(w_idx[:batch], cfg["rows"]) * 100.0 / cfg[
-        "rows"]
+    rows = work.table_rows(cfg)
+    uniq = work.distinct_rows(w_idx[:batch], rows,
+                              cfg["pooling"]) * 100.0 / np.asarray(rows)
     log("unique_access_pct_per_table (first window batch) "
         + " ".join(f"{u:.3f}" for u in uniq))
 
     dep = Deployment(cfg, params, trace=warm_idx if warm else None)
     if not dep.storage.capabilities().device_resident:
-        params["embedding"]["tables"].delete()
+        free(params["embedding"])
     server = loops.Server(dep.session)
     if fault is not None:
         fault(dep.session)
@@ -285,10 +293,8 @@ def run_cell(cfg: dict, traffic: dict, seed: int, seconds: float,
 
     pooled = list(dep.pooled)
     dep.close()
-    tables = params["embedding"]["tables"]
-    if not tables.is_deleted():
-        tables.delete()
-    del dep, server, params, tables
+    free(params["embedding"])
+    del dep, server, params
     gc.unfreeze()
     gc.collect()
     run = Run(cfg, setup_s, window, ps_stats, w_idx, summary,

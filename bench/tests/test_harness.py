@@ -7,7 +7,9 @@ import shutil
 import subprocess
 import sys
 import time
+import types
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -123,3 +125,38 @@ def test_the_control_fails_the_limit():
     ctl, _ = dlrm.reference(5, cfg, made.indices, made.dense, sel,
                             dtype=jnp.bfloat16)
     assert run.gap(ctl, ref) > 3 * cfg["correct"]["logit_gap"]
+
+
+def test_a_ragged_config_reaches_the_program_config():
+    from bench.deploy import model_config
+    cfg = dict(tiny_config(), num_tables=3, rows=[10, 1000, 3],
+               pooling=[1, 5, 2], embedding_args={"batch_block": 16})
+    mc = model_config(cfg)
+    assert mc.embedding.rows == (10, 1000, 3)
+    assert mc.embedding.pooling == (1, 5, 2)
+    assert mc.embedding.batch_block == 16
+    assert mc.bottom_mlp == (64, 32, 16)
+    # model_args reach DLRMConfig, which names the field it lacks
+    with pytest.raises(TypeError, match="cross_layers"):
+        model_config(dict(cfg, model_args={"cross_layers": 3}))
+
+
+def test_the_control_reads_the_reference_the_config_names(monkeypatch):
+    stub = types.ModuleType("bench.references.stub")
+
+    def reference(seed, cfg, indices, dense, select, dtype=jnp.float32,
+                  want_pooled=False):
+        logits = np.ones(len(select), np.float32)
+        return (logits if dtype == jnp.float32 else 1.5 * logits), None
+    stub.reference = reference
+    monkeypatch.setitem(sys.modules, "bench.references.stub", stub)
+    from bench import control
+    cfg = dict(tiny_config(), reference="stub")
+    assert control.readings(cfg, TRAFFIC, 3, 1) == {"logit_gap": 0.5}
+
+
+def test_free_deletes_every_embedding_leaf():
+    tree = {"tables": jnp.zeros(3), "offsets": [jnp.ones(2), jnp.ones(1)]}
+    tree["offsets"][1].delete()
+    run.free(tree)
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(tree))

@@ -58,3 +58,57 @@ def test_fused_bytes_by_hand():
     # 50,000 resident rows of 512 B
     assert work.fused_bytes(cfg, 50_000, 8, 2048) == (
         50_000 * 512 + 8 * (2048 * 150 * 4 + 2048 * 128 * 4))
+
+
+# Counted by work.py at the parent commit of the per-table geometry
+# (792830a), before the model's counts moved to bench/references/dlrm.py:
+# (sum of distinct rows, bag_bytes, step_flops, step_bytes, fused_bytes).
+PINNED = {
+    "dlrm-prod-device32": (5095387, 2681714176, 4744544256, 2684683780,
+                           43819008),
+    "dlrm-prod-tiered4": (149316, 85559296, 2814115840, 88263684, 43819008),
+}
+
+
+@pytest.mark.parametrize("name,tables", [("dlrm-prod-device32", 32),
+                                         ("dlrm-prod-tiered4", 4)])
+def test_counts_are_pinned(name, tables):
+    cfg = config(name)
+    b, t, l = np.ogrid[:2048, :tables, :150]
+    idx = ((b * 7919 + t * 104729 + l * l * 613)
+           % (15_000 * (t + 1))).astype(np.int32)
+    d = work.distinct_rows(idx, cfg["rows"])
+    assert d[:3].tolist() == [15_000, 30_000, 45_000]
+    assert (int(d.sum()), work.bag_bytes(cfg, d, 2048),
+            work.step_flops(cfg, 2048), work.step_bytes(cfg, d, 2048),
+            work.fused_bytes(cfg, 50_000, 8, 2048)) == PINNED[name]
+
+
+RAGGED = {"num_tables": 3, "rows": [10, 1000, 3], "pooling": [1, 5, 2],
+          "dim": 16, "dtype": "float32", "dense_features": 13,
+          "bottom_mlp": [32, 16], "top_mlp": [8, 1], "reference": "dlrm"}
+
+
+def test_ragged_counts_by_hand():
+    # two queries, flat layout: table 0 in column 0, table 1 in 1:6,
+    # table 2 in 6:8
+    idx = np.array([[9, 0, 5, 5, 999, 0, 2, 2],
+                    [9, 7, 7, 7, 7, 7, 0, 1]], np.int32)
+    d = work.distinct_rows(idx, RAGGED["rows"], RAGGED["pooling"])
+    assert d.tolist() == [1, 4, 3]
+    # 8 distinct rows of 16 x 4 B; 2 x 8 ids of 4 B; 2 x 3 pooled rows
+    assert work.bag_bytes(RAGGED, d, 2) == 8 * 64 + 2 * 8 * 4 + 2 * 3 * 64
+    # bags 2 x 8 x 16; towers 13-32-16 and (16 + 6)-8-1; Gram 2 x 4 x 4 x 16
+    mlp = [(13, 32), (32, 16), (22, 8), (8, 1)]
+    assert work.step_flops(RAGGED, 2) == (
+        2 * 8 * 16 + sum(2 * 2 * i * o for i, o in mlp) + 2 * 2 * 16 * 16)
+    assert work.step_bytes(RAGGED, d, 2) == work.bag_bytes(RAGGED, d, 2) + 4 * (
+        sum(i * o + o for i, o in mlp) + 2 * 13 + 2)
+
+
+def test_both_layouts_count_alike():
+    idx = np.arange(2 * 3 * 4, dtype=np.int32).reshape(2, 3, 4) % 5
+    want = work.distinct_rows(idx, 5).tolist()
+    assert work.distinct_rows(idx, np.array([5, 5, 5])).tolist() == want
+    assert work.distinct_rows(idx.reshape(2, 12), [5, 5, 5],
+                              [4, 4, 4]).tolist() == want

@@ -6,8 +6,16 @@ distinct row a batch touches in a table is read once, plus the indices
 read and the pooled rows written. Pinning, dedup or caching can bring
 an implementation closer to them, never below, so a share of the
 roofline computed from them stays under 100 %.
+
+A configuration's `rows` and `pooling` are one int for every table or a
+list of one int per table. What depends on the model (its towers, its
+interaction, its weights) is counted by the reference module that the
+configuration's `reference` names, `bench/references/<reference>.py`,
+as `step_flops(cfg, batch)` and `weight_count(cfg)`.
 """
 from __future__ import annotations
+
+import importlib
 
 import numpy as np
 
@@ -18,13 +26,44 @@ def itemsize(cfg: dict) -> int:
     return np.dtype(cfg["dtype"]).itemsize
 
 
-def distinct_rows(indices: np.ndarray, rows: int) -> np.ndarray:
-    """indices [B, T, L] -> [T] number of distinct rows per table."""
-    out = np.empty(indices.shape[1], np.int64)
-    mark = np.zeros(rows, bool)
-    for t in range(indices.shape[1]):
-        mark[:] = False
-        mark[indices[:, t].ravel()] = True
+def per_table(value, num_tables: int, key: str) -> list:
+    """`value` of `key` for each table: one value stands for every
+    table; a sequence must hold one per table."""
+    if np.ndim(value) == 0:
+        return [value] * num_tables
+    if len(value) != num_tables:
+        raise ValueError(f"{key!r} lists {len(value)} values for "
+                         f"{num_tables} tables")
+    return list(value)
+
+
+def table_rows(cfg: dict) -> list[int]:
+    return per_table(cfg["rows"], cfg["num_tables"], "rows")
+
+
+def table_pooling(cfg: dict) -> list[int]:
+    return per_table(cfg["pooling"], cfg["num_tables"], "pooling")
+
+
+def bags(indices: np.ndarray, pooling=None) -> list[np.ndarray]:
+    """Each table's [B, L_t] ids, from [B, T, L] or from the flat
+    [B, sum(pooling)] layout (table t in columns off[t]:off[t+1],
+    off = cumsum([0] + pooling))."""
+    if indices.ndim == 3:
+        return [indices[:, t] for t in range(indices.shape[1])]
+    off = np.cumsum([0, *pooling])
+    return [indices[:, a:b] for a, b in zip(off[:-1], off[1:])]
+
+
+def distinct_rows(indices: np.ndarray, rows, pooling=None) -> np.ndarray:
+    """indices of a batch in either layout -> [T] number of distinct rows
+    per table. `rows` is one int or one per table; `pooling`, one per
+    table, is needed only for the flat layout."""
+    tables = bags(indices, pooling)
+    out = np.empty(len(tables), np.int64)
+    for t, r in enumerate(per_table(rows, len(tables), "rows")):
+        mark = np.zeros(r, bool)
+        mark[tables[t].ravel()] = True
         out[t] = np.count_nonzero(mark)
     return out
 
@@ -32,9 +71,9 @@ def distinct_rows(indices: np.ndarray, rows: int) -> np.ndarray:
 def bag_bytes(cfg: dict, distinct: np.ndarray, batch: int) -> int:
     """Least bytes of one stacked bag lookup over all tables: distinct
     rows read, indices read, pooled rows written."""
-    t, d, pool = cfg["num_tables"], cfg["dim"], cfg["pooling"]
+    t, d = cfg["num_tables"], cfg["dim"]
     return int(np.sum(distinct) * d * itemsize(cfg)
-               + t * batch * pool * INDEX_BYTES
+               + batch * sum(table_pooling(cfg)) * INDEX_BYTES
                + t * batch * d * itemsize(cfg))
 
 
@@ -48,38 +87,23 @@ def fused_bytes(cfg: dict, hit_rows: int, launches: int, batch: int) -> int:
                              + batch * d * itemsize(cfg)))
 
 
-def _tower(dims: list[int]) -> list[tuple[int, int]]:
-    return list(zip(dims[:-1], dims[1:]))
-
-
-def top_input_dim(cfg: dict) -> int:
-    f = cfg["num_tables"] + 1
-    return cfg["bottom_mlp"][-1] + f * (f - 1) // 2
-
-
-def mlp_shapes(cfg: dict) -> list[tuple[int, int]]:
-    return (_tower([cfg["dense_features"], *cfg["bottom_mlp"]])
-            + _tower([top_input_dim(cfg), *cfg["top_mlp"]]))
+def reference(cfg: dict):
+    """The module of the configuration's plain reference."""
+    return importlib.import_module(f"bench.references.{cfg['reference']}")
 
 
 def step_flops(cfg: dict, batch: int) -> int:
-    """Floating-point operations of one forward over `batch` queries: the
-    bag additions, both MLP towers (multiply and add), and the full
-    Gram matrix of the dot interaction."""
-    t, d, pool = cfg["num_tables"], cfg["dim"], cfg["pooling"]
-    bags = t * batch * pool * d
-    mlps = sum(2 * batch * i * o for i, o in mlp_shapes(cfg))
-    gram = 2 * batch * (t + 1) ** 2 * d
-    return int(bags + mlps + gram)
+    """Floating-point operations of one forward over `batch` queries, as
+    the configuration's reference counts them."""
+    return int(reference(cfg).step_flops(cfg, batch))
 
 
 def step_bytes(cfg: dict, distinct: np.ndarray, batch: int) -> int:
-    """Least bytes of one forward: the bag lookup's, the MLP weights and
-    biases, the dense features read and the logits written."""
-    weights = sum(i * o + o for i, o in mlp_shapes(cfg))
+    """Least bytes of one forward: the bag lookup's, the model's weights,
+    the dense features read and the logits written."""
     return int(bag_bytes(cfg, distinct, batch)
-               + (weights + batch * cfg["dense_features"] + batch)
-               * itemsize(cfg))
+               + (reference(cfg).weight_count(cfg)
+                  + batch * cfg["dense_features"] + batch) * itemsize(cfg))
 
 
 def least_seconds(flops: float, nbytes: float, peaks: dict) -> tuple:
