@@ -1,12 +1,20 @@
 """EmbeddingBagCollection — the paper's embedding stage as a composable module.
 
-Owns a stack of homogeneous embedding tables [T, R, D] (heterogeneous sets are
-grouped into homogeneous collections by the DLRM model), the per-table
-hot-first plans (L2P analogue), and the kernel tuning knobs. Tables are
-processed with a single stacked lookup (one kernel launch over the stack, or
-a vmapped gather), matching the paper's "each GPU executes one or more
-embedding tables serially" — the kernel's table grid axis is the
-serialization.
+Owns the embedding tables, the per-table hot-first plans (L2P analogue),
+and the kernel tuning knobs. Tables are processed with a single lookup (one
+kernel launch over all tables, or a vmapped gather), matching the paper's
+"each GPU executes one or more embedding tables serially".
+
+Layouts (docs/architecture.md, "Table and index layouts"):
+
+* `rows` and `pooling` one int each: the tables are one `[T, R, D]`
+  stack. Either a tuple of one value per table (`per_table`): one
+  `[sum(rows), D]` array, table t in rows
+  `row_offsets()[t]:row_offsets()[t + 1]`.
+* `pooling` one int: a query's indices are `[T, L]`.
+  `pooling` a tuple of one bag size per table: a query's indices are the
+  flat `[sum(pooling)]`, table t's bag in columns
+  `sum(pooling[:t]):sum(pooling[:t + 1])`; no bag is padded.
 
 Storage is pluggable: `EmbeddingStageConfig.storage` names a backend in the
 `repro.storage` registry (`device` — dense XLA/Pallas gather, seed
@@ -52,6 +60,42 @@ def _pool_rows_core(rows_t: jnp.ndarray, w_t: jnp.ndarray | None,
     return pooled
 
 
+#: most table rows `_init_flat_tables` draws at once
+_DRAW_ROWS = 1 << 19
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "dim", "dtype"))
+def _init_flat_tables(rng: jax.Array, *, rows: tuple, dim: int,
+                      dtype) -> jnp.ndarray:
+    """Random `[sum(rows), D]` tables, drawn in chunks of at most
+    `_DRAW_ROWS` rows (a table's last chunk ends at its end, over rows of
+    the one before) and written in place into the one array by a loop, so
+    the device peak is the array plus one chunk."""
+    scale = 1.0 / np.sqrt(dim)
+
+    def draw(key, n):
+        return jax.random.normal(key, (n, dim), dtype) * scale
+    out = jnp.zeros((sum(rows), dim), dtype)
+    for t, (off, r) in enumerate(zip(_offsets(rows), rows)):
+        key = jax.random.fold_in(rng, t)
+        if r <= _DRAW_ROWS:
+            out = jax.lax.dynamic_update_slice(out, draw(key, r), (off, 0))
+            continue
+
+        def chunk(c, out, key=key, off=off, r=r):
+            lo = jnp.minimum(c * _DRAW_ROWS, r - _DRAW_ROWS)
+            return jax.lax.dynamic_update_slice(
+                out, draw(jax.random.fold_in(key, c), _DRAW_ROWS),
+                (off + lo, 0))
+        out = jax.lax.fori_loop(0, -(-r // _DRAW_ROWS), chunk, out)
+    return out
+
+
+def _offsets(sizes) -> tuple:
+    """(0, s0, s0 + s1, ..., sum(sizes))."""
+    return tuple(int(x) for x in np.cumsum([0, *sizes]))
+
+
 @functools.partial(jax.jit, static_argnames=("num_tables", "rows", "dim",
                                              "dtype"))
 def _init_tables(rng: jax.Array, perm: jnp.ndarray | None, *,
@@ -73,9 +117,11 @@ def _init_tables(rng: jax.Array, perm: jnp.ndarray | None, *,
 @dataclasses.dataclass(frozen=True)
 class EmbeddingStageConfig:
     num_tables: int = 250          # paper §V
-    rows: int = 500_000
+    # one count for every table, or a tuple of one per table
+    rows: int | tuple[int, ...] = 500_000
     dim: int = 128
-    pooling: int = 150
+    # one bag size for every table, or a tuple of one per table
+    pooling: int | tuple[int, ...] = 150
     dtype: str = "float32"         # paper: 4-byte precision
     combine: str = "sum"           # bag pooling mode
     # paper-mechanism knobs
@@ -97,12 +143,51 @@ class EmbeddingStageConfig:
     # see EXPERIMENTS.md SPerf iteration C1). 0 = no padding (row-wise plan).
     shard_pad_tables: int = 0
 
+    def __post_init__(self):
+        for key in ("rows", "pooling"):
+            value = getattr(self, key)
+            if isinstance(value, tuple) and len(value) != self.num_tables:
+                raise ValueError(f"{key} lists {len(value)} values for "
+                                 f"{self.num_tables} tables")
+        if self.per_table and self.shard_pad_tables:
+            raise ValueError("shard_pad_tables pads a [T, R, D] stack; "
+                             "tables given per table (rows or pooling a "
+                             "tuple) cannot be padded")
+
     @property
     def jnp_dtype(self):
         return jnp.dtype(self.dtype)
 
+    @property
+    def per_table(self) -> bool:
+        """Rows or bag sizes given one per table: the flat table and
+        index layouts, which only the `device` backend serves."""
+        return isinstance(self.rows, tuple) or isinstance(self.pooling,
+                                                          tuple)
+
+    def table_rows(self) -> tuple:
+        if isinstance(self.rows, tuple):
+            return self.rows
+        return (self.rows,) * self.num_tables
+
+    def table_pooling(self) -> tuple:
+        if isinstance(self.pooling, tuple):
+            return self.pooling
+        return (self.pooling,) * self.num_tables
+
+    def row_offsets(self) -> tuple:
+        """First row of each table in the flat table, and the total."""
+        return _offsets(self.table_rows())
+
+    def query_index_shape(self) -> tuple:
+        """One query's indices: `[sum(pooling)]` when bag sizes are given
+        per table, else `[T, L]`."""
+        if isinstance(self.pooling, tuple):
+            return (sum(self.pooling),)
+        return (self.num_tables, self.pooling)
+
     def table_bytes(self) -> int:
-        return self.num_tables * self.rows * self.dim * self.jnp_dtype.itemsize
+        return sum(self.table_rows()) * self.dim * self.jnp_dtype.itemsize
 
     def kernel_opts(self) -> EmbeddingBagOpts:
         return EmbeddingBagOpts(batch_block=self.batch_block,
@@ -127,10 +212,15 @@ class EmbeddingBagCollection:
         # allocation happens. Lazy import: storage imports core.embedding.
         from repro import storage as storage_registry
         self.storage = storage_registry.create(cfg.storage, self)
+        if cfg.per_table and cfg.pinned_rows > 0:
+            raise ValueError(
+                "pinned_rows stores each table of a [T, R, D] stack "
+                "hot-first; tables given per table (rows or pooling a "
+                "tuple) take pinned_rows=0")
         # One plan per table; identity when pinning is off.
         if plans is None:
-            plans = [hot_cache.identity_plan(cfg.rows, cfg.pinned_rows)
-                     for _ in range(cfg.num_tables)]
+            plans = [hot_cache.identity_plan(r, cfg.pinned_rows)
+                     for r in cfg.table_rows()]
         assert len(plans) == cfg.num_tables
         self.plans = plans
         # [T, R] stacked remap, applied to raw indices before lookup.
@@ -141,6 +231,10 @@ class EmbeddingBagCollection:
     # -- params -------------------------------------------------------------
     def init(self, rng: jax.Array) -> dict:
         cfg = self.cfg
+        if cfg.per_table:
+            return {"tables": _init_flat_tables(
+                rng, rows=cfg.table_rows(), dim=cfg.dim,
+                dtype=cfg.jnp_dtype)}
         perm = None
         if cfg.pinned_rows > 0:
             # Store hot-first (offline, one-time — like the paper's pinning
@@ -164,7 +258,8 @@ class EmbeddingBagCollection:
     def apply(self, params: dict, indices: jnp.ndarray,
               weights: jnp.ndarray | None = None, *,
               pre_remapped: bool = False) -> jnp.ndarray:
-        """indices: [B, T, L] int32 -> pooled [B, T, D].
+        """indices: [B, T, L] or flat [B, sum(pooling)] int32 -> pooled
+        [B, T, D].
 
         Thin delegation into the bound storage backend; which code path
         runs (jitted dense gather, host parameter-server lookup, sharded

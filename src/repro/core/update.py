@@ -39,13 +39,16 @@ class UpdateTxn:
         self.rows = 0
 
     def add(self, table: int, rows: np.ndarray, values: np.ndarray, *,
-            num_tables: int, num_rows: int, dim: int, dtype) -> None:
+            num_tables: int, num_rows, dim: int, dtype) -> None:
+        """`num_rows` is one count for every table or one per table."""
         table = int(table)
         rows = np.asarray(rows, np.int64).ravel()
         values = np.asarray(values)
         if not 0 <= table < num_tables:
             raise ValueError(f"update v{self.version}: table {table} "
                              f"outside [0, {num_tables})")
+        if not np.isscalar(num_rows):
+            num_rows = num_rows[table]
         if rows.size and (rows.min() < 0 or rows.max() >= num_rows):
             raise ValueError(f"update v{self.version}: table {table} rows "
                              f"outside [0, {num_rows})")

@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .kernel import EmbeddingBagOpts, embedding_bag_pallas
+from .kernel import (EmbeddingBagOpts, embedding_bag_flat_pallas,
+                     embedding_bag_pallas)
 
 
 def _on_tpu() -> bool:
@@ -50,6 +51,24 @@ def embedding_bag_stacked(tables: jnp.ndarray, indices: jnp.ndarray,
     indices, weights, batch = _pad_batch(indices, weights, opts.batch_block)
     out = embedding_bag_pallas(tables, indices, weights, opts)
     return out[:, :batch]
+
+
+@functools.partial(jax.jit, static_argnames=("first_rows", "pooling",
+                                             "mode", "opts"))
+def embedding_bag_flat(table: jnp.ndarray, indices: jnp.ndarray,
+                       weights: jnp.ndarray | None = None, *,
+                       first_rows: tuple, pooling: tuple, mode: str = "sum",
+                       opts: EmbeddingBagOpts | None = None) -> jnp.ndarray:
+    """The Pallas kernel over tables of their own rows and bag sizes, one
+    launch: [sum(R),D] x [B,sum(L)] -> [B,T,D] (kernel.py)."""
+    opts = opts or EmbeddingBagOpts()
+    opts = dataclasses.replace(opts, mode=mode,
+                               interpret=opts.interpret or not _on_tpu())
+    indices, weights, batch = _pad_batch(indices, weights, opts.batch_block)
+    out = embedding_bag_flat_pallas(table, indices, weights,
+                                    first_rows=first_rows, pooling=pooling,
+                                    opts=opts)
+    return out[:batch]
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "backend", "opts"))

@@ -6,6 +6,7 @@ This is the semantic ground truth against which the Pallas kernel is verified
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 from jax import ops as jops
 
 
@@ -32,6 +33,36 @@ def embedding_bag_ref(table: jnp.ndarray, indices: jnp.ndarray,
     elif mode != "sum":
         raise ValueError(f"unknown mode {mode!r}")
     return out
+
+
+def embedding_bag_flat_ref(table: jnp.ndarray, indices: jnp.ndarray,
+                           weights: jnp.ndarray | None = None, *,
+                           first_rows, pooling, mode: str = "sum"):
+    """Bags of a bag size per table over one table array.
+
+    table:      [sum(R), D]; table t starts at row first_rows[t]
+    indices:    [B, sum(pooling)] int, table t's bag in its own columns,
+                ids local to the table
+    weights:    [B, sum(pooling)] float or None
+    returns:    [B, T, D]
+    """
+    cols = np.cumsum([0, *pooling])
+    first = np.repeat(np.asarray(first_rows, np.int32), pooling)
+    rows = jnp.take(table, indices + first, axis=0)      # [B, sum(L), D]
+    if weights is not None:
+        rows = rows * weights[..., None].astype(rows.dtype)
+    out = []
+    for a, b in zip(cols[:-1], cols[1:]):
+        bag = rows[:, a:b].sum(axis=1)
+        if mode == "mean":
+            denom = (jnp.maximum(weights[:, a:b].sum(axis=1), 1e-9)[:, None]
+                     if weights is not None
+                     else jnp.asarray(b - a, dtype=rows.dtype))
+            bag = bag / denom
+        elif mode != "sum":
+            raise ValueError(f"unknown mode {mode!r}")
+        out.append(bag)
+    return jnp.stack(out, axis=1)
 
 
 def embedding_bag_ragged_ref(table: jnp.ndarray, flat_indices: jnp.ndarray,

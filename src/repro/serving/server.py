@@ -42,7 +42,11 @@ from jax.profiler import TraceAnnotation
 class Query:
     qid: int
     dense: np.ndarray          # [F]
-    indices: np.ndarray        # [T, L]
+    # int32 row ids, in the layout the embedding configuration states
+    # (EmbeddingStageConfig.query_index_shape): [T, L] when every table
+    # has bags of L rows; the flat [sum(L)] when bag sizes are given per
+    # table, table t's bag in columns sum(L[:t]):sum(L[:t + 1])
+    indices: np.ndarray
     # None = stamped by the batcher at submit time (live traffic); replay
     # drivers preset the trace's nominal arrival so latency accounting
     # reflects offered load even when the server is behind
@@ -218,7 +222,7 @@ class ServeStats:
 
 
 class InferenceServer:
-    """forward(dense [B,F], indices [B,T,L]) -> scores [B].
+    """forward(dense [B,F], indices [B,T,L] or [B,sum(L)]) -> scores [B].
 
     Pass the model's storage backend as `storage` (any
     `repro.storage.EmbeddingStorage`): the server then (a) stages the NEXT
@@ -280,7 +284,8 @@ class InferenceServer:
 
     @staticmethod
     def _assemble_indices(batch: list[Query], b: int) -> np.ndarray:
-        """[b, T, L] int32 index tensor; rows past len(batch) stay zero
+        """[b, T, L] or flat [b, sum(L)] int32 index tensor, the queries'
+        own layout; rows past len(batch) stay zero
         (the padding hint_valid() later excludes from PS stats). Shared by
         _assemble and _stage_next so staged indices always match the
         upcoming lookup's bit-for-bit (consume() matches on equality)."""
@@ -290,7 +295,7 @@ class InferenceServer:
         return idx
 
     def _assemble(self, batch: list[Query], b: int):
-        """[b, F] dense and [b, T, L] indices; rows past len(batch) stay
+        """[b, F] dense and [b, ...] indices; rows past len(batch) stay
         zero."""
         dense = np.zeros((b,) + batch[0].dense.shape, np.float32)
         for i, q in enumerate(batch):

@@ -172,9 +172,11 @@ class ServingSession:
                 # as the copy is issued, so that it starts the moment the
                 # copy lands; the span then waits for the copy alone.
                 # (Waiting before the dispatch cost 1–3 ms a batch on a
-                # v5e.)
+                # v5e.) `lookups` counts the row ids copied, padding
+                # queries included.
                 with TraceAnnotation("serve.put",
-                                     bytes=dense.nbytes + idx.nbytes):
+                                     bytes=dense.nbytes + idx.nbytes,
+                                     lookups=idx.size):
                     dense, idx = jax.device_put((dense, idx))
                     scores = jitted(self.params, dense, idx)
                     jax.block_until_ready((dense, idx))
@@ -197,8 +199,8 @@ class ServingSession:
         cfg = self.model.cfg
         for batch in batch_sizes:
             dense = np.zeros((batch, cfg.dense_features), np.float32)
-            idx = np.zeros((batch, cfg.embedding.num_tables,
-                            cfg.embedding.pooling), np.int32)
+            idx = np.zeros((batch, *cfg.embedding.query_index_shape()),
+                           np.int32)
             jax.block_until_ready(self._forward(dense, idx))
         self.storage.flush()
         self.storage.reset_stats()

@@ -136,7 +136,14 @@ class TenantManager:
     @staticmethod
     def _check_geometry(specs: list) -> None:
         """Tenants share one table AXIS, so row count / dim / dtype /
-        combine must agree; table count and pooling are per-tenant."""
+        combine must agree; table count and pooling are per-tenant.
+        Rows or bag sizes given per table have no such axis."""
+        for s in specs:
+            if s.model.ebc.cfg.per_table:
+                raise ValueError(
+                    f"tenant {s.name!r} gives rows or pooling per table — "
+                    "tenants share one (rows, dim, dtype, combine) table "
+                    "axis of equal tables and equal bags")
         first = specs[0].model.ebc.cfg
         for s in specs[1:]:
             c = s.model.ebc.cfg

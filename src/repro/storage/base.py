@@ -137,10 +137,19 @@ class EmbeddingStorage(abc.ABC):
 
     #: registry key; set by `repro.storage.registry.register`
     name: ClassVar[str] = "?"
+    #: serves rows or bag sizes given per table (`EmbeddingStageConfig.
+    #: per_table`: a `[sum(R), D]` table, flat `[B, sum(L)]` indices)
+    per_table_geometry: ClassVar[bool] = False
 
     def __init__(self, ebc):
         self.ebc = ebc
         self.cfg = None if ebc is None else ebc.cfg
+        if (self.cfg is not None and self.cfg.per_table
+                and not self.per_table_geometry):
+            raise ValueError(
+                f"storage={self.name!r} serves tables of one row count and "
+                f"one bag size, a [T, R, D] stack under [B, T, L] indices; "
+                f"rows or pooling given per table need storage='device'")
 
     # -- descriptor ---------------------------------------------------------
     @abc.abstractmethod
@@ -163,7 +172,9 @@ class EmbeddingStorage(abc.ABC):
     @abc.abstractmethod
     def lookup(self, params: dict, indices, weights=None, *,
                pre_remapped: bool = False):
-        """indices [B, T, L] -> pooled [B, T, D], bit-exact across backends."""
+        """indices [B, T, L] (or flat [B, sum(L)] where the backend has
+        `per_table_geometry`) -> pooled [B, T, D], bit-exact across
+        backends."""
         ...
 
     # -- prefetch (overlap) hooks -------------------------------------------

@@ -1,10 +1,15 @@
 """`device` backend — tables fully HBM-resident, dense XLA/Pallas gather.
 
 The seed behaviour (every table fits on device), re-homed behind the
-`EmbeddingStorage` protocol. `lookup()` is the jit-traceable dense path:
-hot-first remap, optional table-stack padding for whole-table sharding,
-then either a vmapped `jnp.take` (XLA baseline) or the Pallas
-slab-gather embedding-bag kernel, and the shared pooling reduction.
+`EmbeddingStorage` protocol. `lookup()` is the jit-traceable dense path.
+For a `[T, R, D]` stack and `[B, T, L]` indices: hot-first remap,
+optional table-stack padding for whole-table sharding, then either a
+vmapped `jnp.take` (XLA baseline) or the Pallas slab-gather kernel's
+(table, batch block) grid, and the shared pooling reduction. Rows or bag
+sizes per table (`EmbeddingStageConfig.per_table`) are drawn as one
+`[sum(R), D]` table and read with a first row per table, from `[B, T, L]`
+or flat `[B, sum(L)]` indices, by the kernel's flat form or its XLA
+reference. The table's layout decides the form, never a flag.
 
 No staging, no refresh: with everything resident there is nothing to
 overlap or re-pin at the storage level (the paper's in-kernel prefetch
@@ -17,7 +22,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.embedding_bag import embedding_bag_stacked
+from repro.kernels.embedding_bag import (embedding_bag_flat,
+                                         embedding_bag_flat_ref,
+                                         embedding_bag_stacked)
 from repro.storage.base import EmbeddingStorage, StorageCapabilities
 from repro.storage.registry import register
 
@@ -30,7 +37,10 @@ class DeviceStorage(EmbeddingStorage):
     binds it (same object the serving engine reads each call), and
     `commit_update` replaces `params["tables"]` with a scattered copy —
     logical row ids route through the EBC's hot-first remap, since the
-    stored tables are physically permuted when `pinned_rows > 0`."""
+    stored tables are physically permuted when `pinned_rows > 0`, and
+    through the table's first row in a `[sum(R), D]` table."""
+
+    per_table_geometry = True
 
     def __init__(self, ebc):
         super().__init__(ebc)
@@ -77,7 +87,7 @@ class DeviceStorage(EmbeddingStorage):
         cfg = self.cfg
         require_open(self._update_txn, "apply_update").add(
             table, rows, values, num_tables=cfg.num_tables,
-            num_rows=cfg.rows, dim=cfg.dim, dtype=cfg.jnp_dtype)
+            num_rows=cfg.table_rows(), dim=cfg.dim, dtype=cfg.jnp_dtype)
         return True
 
     def commit_update(self, version: int) -> dict:
@@ -87,10 +97,14 @@ class DeviceStorage(EmbeddingStorage):
         merged = txn.merged()
         tables = self._params["tables"]
         applied = 0
+        first = self.cfg.row_offsets()
         for t, (rows, vals) in merged.items():
             phys = (rows if self.ebc._remap is None
                     else self.ebc._remap[t][rows])
-            tables = tables.at[t, phys].set(vals)
+            if tables.ndim == 3:
+                tables = tables.at[t, phys].set(vals)
+            else:
+                tables = tables.at[first[t] + phys].set(vals)
             applied += int(rows.size)
         # same dict object the engine reads per call: the swap is visible
         # on the NEXT forward, never mid-batch
@@ -109,12 +123,20 @@ class DeviceStorage(EmbeddingStorage):
 
     def lookup(self, params: dict, indices, weights=None, *,
                pre_remapped: bool = False):
-        """indices: [B, T, L] int32 -> pooled [B, T, D] (jit-traceable)."""
+        """indices: [B, T, L] or flat [B, sum(L)] int32 -> pooled
+        [B, T, D] (jit-traceable)."""
         from repro.core.embedding import _pool_rows_core
         cfg = self.cfg
+        tables = params["tables"]           # [T(+pad), R, D] or [sum(R), D]
+        if tables.ndim == 2:
+            return self._lookup_flat(tables, indices, weights)
+        if indices.ndim != 3:
+            raise ValueError(
+                f"a [T, R, D] table stack takes [B, T, L] indices, got "
+                f"shape {indices.shape}: bag sizes per table need the "
+                f"[sum(R), D] table that init() draws for them")
         if not pre_remapped:
             indices = self.ebc.remap_indices(indices)
-        tables = params["tables"]                      # [T(+pad), R, D]
         idx_t = jnp.swapaxes(indices, 0, 1)            # [T, B, L]
         w_t = None if weights is None else jnp.swapaxes(weights, 0, 1)
         if cfg.shard_pad_tables:
@@ -149,3 +171,22 @@ class DeviceStorage(EmbeddingStorage):
         if cfg.shard_pad_tables:
             pooled = pooled[:, :cfg.num_tables]
         return pooled
+
+    def _lookup_flat(self, tables, indices, weights):
+        """Rows or bag sizes per table: one `[sum(R), D]` table, each
+        query's bags as its flat `[sum(L)]` indices."""
+        cfg = self.cfg
+        batch = indices.shape[0]
+        pooling = cfg.table_pooling()
+        first = cfg.row_offsets()[:-1]
+        indices = indices.reshape(batch, -1)
+        if weights is not None:
+            weights = weights.reshape(batch, -1)
+        if cfg.backend == "xla" or (cfg.backend == "auto"
+                                    and jax.default_backend() != "tpu"):
+            return embedding_bag_flat_ref(tables, indices, weights,
+                                          first_rows=first, pooling=pooling,
+                                          mode=cfg.combine)
+        return embedding_bag_flat(tables, indices, weights, first_rows=first,
+                                  pooling=pooling, mode=cfg.combine,
+                                  opts=cfg.kernel_opts())

@@ -1,7 +1,8 @@
 """Compile-only checks of the serving kernels for a described TPU v5e.
 
 Nothing runs: each test lowers and compiles one program at the paper's
-widths (B=2048, L=150, D=128, R=500K rows, float32) for a v5e chip that
+widths (B=2048, L=150, D=128, R=500K rows, float32), or at MLPerf
+DLRM-DCNv2's (26 tables of their own rows and bag sizes), for a v5e chip that
 is described, not attached, and asserts that the Pallas kernel is in the
 compiled program (`tpu_custom_call`). This catches what interpret mode
 cannot — tiling alignment, SMEM/VMEM budgets, operands Mosaic will not
@@ -147,6 +148,39 @@ def test_bag_kernel_slab_fits_scoped_vmem(one_chip, monkeypatch, budget):
         return
     assert bag_kernel.bags_per_step(opts, long_bags, D) == 2
     assert "%embedding_bag." in lowered.compile().as_text()
+
+
+# MLPerf DLRM-DCNv2 on one chip of 16 (bench/configs/dlrm-dcnv2-share16.json)
+DCN_ROWS = (2_500_000, 39060, 17295, 7424, 20265, 3, 7122, 1543, 63,
+            2_500_000, 3067956, 405282, 10, 2209, 11938, 155, 4, 976, 14,
+            2_500_000, 2_500_000, 2_500_000, 590152, 12973, 108, 36)
+DCN_BAGS = (3, 2, 1, 2, 6, 1, 1, 1, 1, 7, 3, 8, 1, 6, 9, 5, 1, 1, 1, 12, 100,
+            27, 10, 3, 1, 1)
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+def test_flat_lookup_compiles_at_dcnv2_shapes(one_chip, monkeypatch,
+                                              weighted):
+    """The `device` lookup of 26 tables of their own rows (one
+    `[16,684,588, 128]` table, within 0.6 % of 2**31 elements) under the
+    published bag sizes, 214 ids a query in the flat layout: ONE kernel
+    launch for all tables, its blocks under the VMEM budget."""
+    monkeypatch.setattr(bag_ops, "_on_tpu", lambda: True)
+    assert sum(DCN_ROWS) == 16_684_588 and sum(DCN_BAGS) == 214
+    cfg = EmbeddingStageConfig(num_tables=26, rows=DCN_ROWS, dim=D,
+                               pooling=DCN_BAGS, backend="pallas")
+    opts = cfg.kernel_opts()
+    assert opts.vmem_bytes(DCN_BAGS, D) <= bag_kernel.VMEM_BUDGET
+    assert bag_kernel.bags_per_step(opts, DCN_BAGS, D) == opts.batch_block
+    ebc = EmbeddingBagCollection(cfg)
+    params = {"tables": _spec((sum(DCN_ROWS), D), jnp.float32, one_chip)}
+    idx = _spec((B, 214), jnp.int32, one_chip)
+    w = _spec((B, 214), jnp.float32, one_chip) if weighted else None
+    lowered = jax.jit(ebc.apply).lower(params, idx, w)
+    assert lowered.out_info.shape == (B, 26, D)
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%embedding_bag." in text
 
 
 def test_bag_kernel_refuses_bf16_tables(one_chip):
