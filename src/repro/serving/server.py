@@ -5,6 +5,12 @@ to saturate the GPU; same logic here), the engine executes the forward pass,
 and per-query latencies are tracked against an SLA target. Percentile
 reporting mirrors how the paper reports batch latency.
 
+Dispatch-ahead (see docs/serving.md, "When a poll dispatches ahead"):
+with a device-resident engine on an accelerator and the real clock, a
+poll dispatches the next ready batch before it waits for the current
+one's logits, so the device has the next batch queued while the host
+records, returns and assembles.
+
 Storage integration (see docs/serving.md): the server drives any
 `repro.storage.EmbeddingStorage` backend generically through the protocol —
 no backend-specific code in the loop, so every current and future backend
@@ -34,6 +40,7 @@ import itertools
 import time
 from typing import Callable, Optional
 
+import jax
 import numpy as np
 from jax.profiler import TraceAnnotation
 
@@ -94,6 +101,11 @@ class Batcher:
     `clock` abstracts time for the batching window and arrival stamps —
     the default is the real `time.perf_counter`; replay harnesses pass a
     `repro.traffic.VirtualClock` so offered load is deterministic.
+
+    `queue` holds every admitted query not answered yet, oldest first. A
+    batch `next_batch` hands out stays at the head of it, in flight,
+    until `answered` takes it off, so an empty queue means everything
+    admitted was answered. `in_flight` counts those queries.
     """
 
     #: EWMA smoothing for the observed batch service time (deadline
@@ -105,6 +117,7 @@ class Batcher:
         self.cfg = cfg
         self.clock = clock if clock is not None else time.perf_counter
         self.queue: collections.deque[Query] = collections.deque()
+        self.in_flight = 0
         self.shed = 0
         self.shed_reasons: collections.Counter = collections.Counter()
         self.service_ewma_s: Optional[float] = None
@@ -121,7 +134,8 @@ class Batcher:
         query cannot be served usefully: the queue bound is hit, or the
         predicted wait to its batch's completion already exceeds the
         deadline budget. Runs BEFORE the query is queued, so a shed query
-        costs no assembly or service work at all."""
+        costs no assembly or service work at all. Both count the queries
+        in flight: they are admitted and not answered yet."""
         cfg = self.cfg
         qlen = len(self.queue)
         if cfg.max_queue and qlen >= cfg.max_queue:
@@ -147,20 +161,52 @@ class Batcher:
             q.arrival_s = self.clock()
         self.queue.append(q)
 
+    def waiting(self) -> int:
+        """Queries admitted and not handed out yet."""
+        return len(self.queue) - self.in_flight
+
     def next_batch(self, force: bool = False) -> Optional[list[Query]]:
-        """A full batch, or a partial one once the head query's batching
-        window has elapsed. `force=True` flushes a partial batch
-        immediately (drain/shutdown path)."""
-        if not self.queue:
+        """Hand out the oldest waiting queries: a full batch, or a partial
+        one once the oldest waiting query's batching window has elapsed.
+        `force=True` flushes a partial batch immediately (drain/shutdown
+        path). The batch stays queued until `answered`."""
+        waiting = self.waiting()
+        if not waiting:
             return None
-        deadline = self.queue[0].arrival_s + self.cfg.max_wait_s
-        if (not force and len(self.queue) < self.cfg.max_batch
-                and self.clock() < deadline):
+        head = self.queue[self.in_flight]
+        if (not force and waiting < self.cfg.max_batch
+                and self.clock() < head.arrival_s + self.cfg.max_wait_s):
             return None
-        out = []
-        while self.queue and len(out) < self.cfg.max_batch:
-            out.append(self.queue.popleft())
+        out = list(itertools.islice(self.queue, self.in_flight,
+                                    self.in_flight + self.cfg.max_batch))
+        self.in_flight += len(out)
         return out
+
+    def answered(self, n: int) -> None:
+        """The oldest `n` queries handed out were answered (or given up):
+        they leave the queue."""
+        for _ in range(n):
+            self.queue.popleft()
+        self.in_flight -= n
+
+
+def _device_beside_host() -> bool:
+    """Whether JAX's default device is an accelerator of its own. On the
+    CPU backend the engine computes on the host's own cores, the ones the
+    serving loop's host work runs on, so a batch dispatched ahead has no
+    separate device to keep busy: the poll stays serial there."""
+    return jax.default_backend() != "cpu"
+
+
+@dataclasses.dataclass
+class _Flight:
+    """A batch handed out by the batcher and not answered yet: the time
+    it was handed out, its device batch size, and once dispatched, the
+    engine's scores (still being computed while the device runs it)."""
+    batch: list
+    padded: int
+    popped: float
+    scores: object = None
 
 
 @dataclasses.dataclass
@@ -269,6 +315,16 @@ class InferenceServer:
         # online-update bench uses it to check each response bit-exactly
         # against the model version its query was pinned to.
         self.on_batch: Optional[Callable] = None
+        # dispatch-ahead (see poll): only where the engine's forward is
+        # asynchronous (device-resident storage), runs on a device beside
+        # the host, and time is real. A virtual clock advances by serial
+        # service time, and host-backed lookups run synchronously, in
+        # step with their refresh schedule.
+        self._ahead_ok = (self._clock_advance is None
+                          and storage is not None
+                          and storage.capabilities().device_resident
+                          and _device_beside_host())
+        self._in_flight: Optional[_Flight] = None
 
     def submit(self, q: Query) -> None:
         """Admit or shed one query. A shed query raises `QueryShedError`
@@ -308,12 +364,14 @@ class InferenceServer:
         full batch is staged — its contents are then FIFO-deterministic, so
         the staged indices exactly match the upcoming lookup. Backpressure
         is checked before any assembly work, and only the indices are
-        assembled (staging never needs the dense features)."""
-        q = self.batcher.queue
-        b = self.batcher.cfg.max_batch
-        if len(q) < b or not self.storage.can_stage():
+        assembled (staging never needs the dense features). The queries
+        in flight are skipped: they are already looked up."""
+        batcher = self.batcher
+        b = batcher.cfg.max_batch
+        if batcher.waiting() < b or not self.storage.can_stage():
             return
-        nxt = list(itertools.islice(q, b))
+        nxt = list(itertools.islice(batcher.queue, batcher.in_flight,
+                                    batcher.in_flight + b))
         self.storage.stage(self._assemble_indices(nxt, b))
 
     # -- async refresh driver -----------------------------------------------
@@ -351,32 +409,67 @@ class InferenceServer:
         self.stats.ps_stats = self.storage.stats()
 
     def poll(self, force: bool = False) -> int:
-        """Execute at most one batch; returns #queries served.
+        """Answer at most one batch; returns #queries served.
+
+        The batch answered is the one in flight, if any, else the one the
+        batcher hands out now. Where dispatch-ahead is sound (a
+        device-resident engine on an accelerator, on the real clock) and
+        `force` is not set, the poll first assembles, copies and
+        dispatches the NEXT batch, if the batcher would hand one out now,
+        so that it is queued on the device behind the answered one and the
+        host's work between batches overlaps the device's. That batch
+        stays in flight, and its queries in `batcher.queue`, until the
+        next poll answers it.
 
         A poll that serves a batch runs under the profiler span
-        `serve.batch`, whose children split it: `serve.assemble`,
-        `serve.stage`, `serve.forward` (exactly the batch service time)
-        and `serve.record`. Their statistics are per-batch sums, so no
-        span is opened per query; with no profiler running a span costs
-        about a microsecond (docs/serving.md, "Tracing a served batch")."""
+        `serve.batch`, whose children split it: `serve.assemble` and
+        `serve.stage` for each batch dispatched, `serve.forward` (exactly
+        the batch service time: the dispatches, then the wait for the
+        answered batch's logits) and `serve.record`. Their statistics are
+        per-batch sums, so no span is opened per query; with no profiler
+        running a span costs about a microsecond (docs/serving.md,
+        "Tracing a served batch")."""
+        cur = self._in_flight
+        if cur is None:
+            cur = self._hand_out(force)
+            if cur is None:
+                return 0
+        nxt = None if force or not self._ahead_ok else self._hand_out()
+        self._in_flight = nxt
+        batch = cur.batch
+        n = len(batch)
+        try:
+            # queue waits as sums: the batch is FIFO, so its head waited
+            # most
+            with TraceAnnotation(
+                    "serve.batch", queries=n, padded=cur.padded,
+                    wait_s_sum=n * cur.popped
+                    - sum(q.arrival_s for q in batch),
+                    wait_s_max=cur.popped - batch[0].arrival_s,
+                    ahead=int(nxt is not None)):
+                return self._serve(cur, nxt)
+        except BaseException:
+            # give up what was handed out, as a serial poll always did
+            # with the batch it held: those queries leave the queue
+            # unanswered, and a later poll starts afresh
+            self._in_flight = None
+            self.batcher.answered(self.batcher.in_flight)
+            raise
+
+    def _hand_out(self, force: bool = False) -> Optional[_Flight]:
         batch = self.batcher.next_batch(force=force)
         if not batch:
-            return 0
-        popped = self.clock()
-        n = len(batch)
+            return None
         cfg = self.batcher.cfg
-        b = cfg.max_batch if cfg.pad_to_max else n
-        # queue waits as sums: the batch is FIFO, so its head waited most
-        with TraceAnnotation(
-                "serve.batch", queries=n, padded=b,
-                wait_s_sum=n * popped - sum(q.arrival_s for q in batch),
-                wait_s_max=popped - batch[0].arrival_s):
-            return self._serve(batch, b)
+        return _Flight(batch, cfg.max_batch if cfg.pad_to_max
+                       else len(batch), self.clock())
 
-    def _serve(self, batch: list[Query], b: int) -> int:
-        n = len(batch)
+    def _prepare(self, flight: _Flight):
+        """Assemble a batch about to be dispatched and run the storage
+        hooks before its lookup; its dense and index arrays."""
+        n = len(flight.batch)
         with TraceAnnotation("serve.assemble"):
-            dense, idx = self._assemble(batch, b)
+            arrays = self._assemble(flight.batch, flight.padded)
         if self.storage is not None:
             # both run outside the timed region. Install a finished
             # refresh FIRST so staging probes the post-refresh tier state
@@ -390,14 +483,23 @@ class InferenceServer:
                 # batcher padding is not traffic — keep it out of cache
                 # stats and the refresh window
                 self.storage.hint_valid(n)
+        return arrays
+
+    def _serve(self, cur: _Flight, nxt: Optional[_Flight]) -> int:
+        batch = cur.batch
+        n = len(batch)
+        todo = [f for f in (cur, nxt) if f is not None and f.scores is None]
+        arrays = [self._prepare(f) for f in todo]
         with TraceAnnotation("serve.forward"):
             t0 = time.perf_counter()
-            scores = self.forward(dense, idx)
-            np.asarray(scores)  # block
+            for f, (dense, idx) in zip(todo, arrays):
+                f.scores = self.forward(dense, idx)
+            scores = np.asarray(cur.scores)  # block
             t1 = time.perf_counter()
         with TraceAnnotation("serve.record"):
+            self.batcher.answered(n)
             if self.on_batch is not None:
-                self.on_batch(batch, np.asarray(scores)[:n])
+                self.on_batch(batch, scores[:n])
             # batch service time is always REAL seconds (it feeds the
             # deadline admission's EWMA); a virtual clock advances by
             # exactly that duration, so query latencies = virtual queueing

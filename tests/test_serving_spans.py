@@ -4,7 +4,10 @@ and the trace is read back. Each served batch opens `serve.batch` with
 `serve.assemble`, `serve.stage`, `serve.forward` (holding `serve.put`)
 and `serve.record` inside it, in that order; the statistics are the
 batch's sums; a poll that serves nothing opens no span; and the answers
-are the same bits with the profiler on as off."""
+are the same bits with the profiler on as off. These run on a virtual
+clock, so every poll is serial. Under dispatch-ahead, each batch is
+assembled and put once, by the poll that dispatches it, and `ahead`
+marks the polls that did."""
 import glob
 import os
 
@@ -17,6 +20,7 @@ from repro.core import EmbeddingStageConfig
 from repro.models.dlrm import DLRM, DLRMConfig
 from repro.ps import PSConfig
 from repro.serving import BatcherConfig, Query, ServingSession
+from repro.serving import server as server_mod
 from repro.traffic import VirtualClock
 
 ROWS, TABLES, DIM, POOL, F, B = 200, 3, 16, 4, 13, 32
@@ -173,3 +177,35 @@ def test_the_host_backed_engine_opens_a_lookup_and_no_put(tmp_path):
     forward, = [e for e in events if e[0] == "serve.forward"]
     assert len(_inside(forward, events, "serve.lookup")) == 1
     assert not [e for e in events if e[0] == "serve.put"]
+
+
+def test_a_pipelined_poll_assembles_and_puts_each_batch_once(tmp_path,
+                                                            monkeypatch):
+    """Dispatch-ahead, on the real clock with the server's accelerator
+    check steered: three full batches queued at once take three polls.
+    The first dispatches two batches, the second one, the third none;
+    each dispatch is one `serve.assemble` and one `serve.put`, the put
+    inside the `serve.forward` of the poll that dispatched it, and the
+    `ahead` statistic marks the polls that dispatched ahead."""
+    monkeypatch.setattr(server_mod, "_device_beside_host", lambda: True)
+    with _session(None) as sess:
+        for q in _queries(2, 3 * B):
+            sess.submit(q)
+        with jax.profiler.trace(str(tmp_path)):
+            served = [sess.poll() for _ in range(4)]
+    assert served == [B, B, B, 0]
+    events = _host_spans(str(tmp_path))
+    batches = sorted((e for e in events if e[0] == "serve.batch"),
+                     key=lambda e: e[1])
+    assert [b[3]["ahead"] for b in batches] == [1, 1, 0]
+    assert [b[3]["queries"] for b in batches] == [B, B, B]
+    dispatched = [2, 1, 0]
+    for batch, n in zip(batches, dispatched):
+        assert len(_inside(batch, events, "serve.assemble")) == n
+        assert len(_inside(batch, events, "serve.put")) == n
+        forward, = _inside(batch, events, "serve.forward")
+        assert len(_inside(forward, events, "serve.put")) == n
+        assert len(_inside(batch, events, "serve.record")) == 1
+    puts = [e for e in events if e[0] == "serve.put"]
+    assert len(puts) == sum(dispatched) == len(batches)
+    assert {p[3]["bytes"] for p in puts} == {B * F * 4 + B * TABLES * POOL * 4}
