@@ -6,8 +6,8 @@ Output: ``name,us_per_call,derived`` CSV rows.
   derived     — TPU-v5e modeled value from benchmarks/tpu_model.py or an
                 exact dataset statistic (hit rates, coverage, unique%).
 
-Scaled-down workload (CPU-feasible) unless noted; the full paper config
-(250 x 500K x 128, B=2048, pool 150) runs through the dry-run path instead.
+Scaled-down workload (CPU-feasible) unless noted; the paper's widths
+(B=2048, pool 150, D=128) run on the chip through `bench/run.py`.
 
 CLI: ``--sweep NAME`` (repeatable) runs a subset; ``--backend
 {device,tiered,sharded,...}`` routes the `storage_backends` sweep through

@@ -24,11 +24,12 @@ delegates to `self.storage.lookup(...)`. All backends are bit-exact with
 the dense gather; see docs/architecture.md for the layer map and
 docs/serving.md for the old→new migration table.
 
-Distribution: table-wise sharding over the `model` mesh axis (stack axis 0),
-batch over `data` — the classic DLRM hybrid parallelism. The all-to-all that
-moves lookup outputs from model-parallel to data-parallel layout is inserted
-by XLA under jit from the in/out shardings (an explicit shard_map variant is
-exercised in launch/steps.py as the optimized path).
+Distribution: the `device` backend serves one chip and uses no mesh; every
+table it holds lives whole on that chip. A deployment larger than one chip
+is served as one chip's share of its tables (`shard_pad_tables` pads that
+share's stack with zero tables, which every lookup then also reads). The
+`sharded` and `pool` backends split tables over host threads and
+processes, not over chips.
 """
 from __future__ import annotations
 
@@ -138,9 +139,8 @@ class EmbeddingStageConfig:
     # this gains nothing, and remapping the indices costs time (76 ms a
     # batch at 24 tables x 500,000 rows on a v5e)
     pinned_rows: int = 0
-    # pad the table stack so it divides the global device count -> each device
-    # owns whole tables (table-parallel a2a plan; beyond-paper optimization,
-    # see EXPERIMENTS.md SPerf iteration C1). 0 = no padding (row-wise plan).
+    # zero tables appended to the stack, so that a deployment's table count
+    # divides its chip count; every lookup also reads them. 0 = no padding
     shard_pad_tables: int = 0
 
     def __post_init__(self):

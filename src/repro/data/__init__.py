@@ -1,2 +1,1 @@
-from repro.data.pipeline import (HETERO_MIXES, DLRMBatch, DLRMQueryStream,
-                                 TokenStream)
+from repro.data.pipeline import HETERO_MIXES, DLRMBatch, DLRMQueryStream

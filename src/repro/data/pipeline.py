@@ -1,10 +1,7 @@
 """Deterministic, resumable synthetic data pipelines.
 
-Two families:
-  * DLRMQueryStream — dense + categorical features with per-table hotness
-    (paper §V datasets; heterogeneous mixes per Table VII).
-  * TokenStream — LM token batches (Zipf-distributed vocabulary, so the
-    pinned-vocab gather path sees realistic skew).
+DLRMQueryStream — dense + categorical features with per-table hotness
+(paper §V datasets; heterogeneous mixes per Table VII).
 
 Determinism contract: state is (seed, step). `state_dict()`/`load_state_dict`
 round-trip exactly; a restored stream reproduces the same batches — this is
@@ -13,11 +10,11 @@ what checkpoint/restart tests assert.
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterator, Optional, Sequence
+from typing import Iterator, Sequence
 
 import numpy as np
 
-from repro.core.access_patterns import AccessPattern, make_pattern
+from repro.core.access_patterns import make_pattern
 
 # paper Table VII heterogeneous mixtures (counts per hotness level)
 HETERO_MIXES = {
@@ -105,38 +102,3 @@ class DLRMQueryStream:
         assert st["seed"] == self.seed, "stream seed mismatch on restore"
         self.step = int(st["step"])
 
-
-class TokenStream:
-    """Zipf-vocabulary LM batches, shard-aware for data parallelism."""
-
-    def __init__(self, *, vocab_size: int, seq_len: int, global_batch: int,
-                 zipf_alpha: float = 1.1, seed: int = 0,
-                 shard: int = 0, num_shards: int = 1):
-        assert global_batch % num_shards == 0
-        self.vocab_size = vocab_size
-        self.seq_len = seq_len
-        self.local_batch = global_batch // num_shards
-        self.shard = shard
-        self.num_shards = num_shards
-        self.seed = seed
-        self.step = 0
-        ranks = np.arange(1, vocab_size + 1, dtype=np.float64)
-        w = ranks ** (-zipf_alpha)
-        self._cdf = np.cumsum(w / w.sum())
-
-    def next_batch(self) -> dict:
-        rng = np.random.default_rng(
-            (self.seed << 24) ^ (self.step * self.num_shards + self.shard))
-        n = self.local_batch * (self.seq_len + 1)
-        u = rng.random(n)
-        toks = np.searchsorted(self._cdf, u).astype(np.int32).reshape(
-            self.local_batch, self.seq_len + 1)
-        self.step += 1
-        return {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
-
-    def state_dict(self) -> dict:
-        return {"seed": self.seed, "step": self.step, "shard": self.shard}
-
-    def load_state_dict(self, st: dict) -> None:
-        assert st["seed"] == self.seed and st["shard"] == self.shard
-        self.step = int(st["step"])

@@ -1,6 +1,1 @@
-from repro.models.config import SHAPES, ModelConfig, ShapeConfig, shapes_for
 from repro.models.dlrm import DLRM, DLRMConfig
-from repro.models.encdec import WhisperModel
-from repro.models.registry import (abstract_params, analytic_param_count,
-                                   build_model, model_flops, param_count)
-from repro.models.transformer import TransformerLM, build_plan

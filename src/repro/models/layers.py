@@ -1,4 +1,4 @@
-"""Primitive layers: norms, MLPs, RoPE, init helpers. Functional (dict params)."""
+"""DLRM's MLP towers and their init. Functional (dict params)."""
 from __future__ import annotations
 
 import jax
@@ -12,50 +12,6 @@ def dense_init(rng, shape, dtype, scale: float | None = None):
     scale = scale if scale is not None else 1.0 / np.sqrt(fan_in)
     return (jax.random.truncated_normal(rng, -2, 2, shape, jnp.float32)
             * scale).astype(dtype)
-
-
-def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-5):
-    dt = x.dtype
-    x = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    x = x * jax.lax.rsqrt(var + eps)
-    return (x * (1.0 + weight.astype(jnp.float32))).astype(dt)
-
-
-def layer_norm(x, weight, bias, eps: float = 1e-5):
-    dt = x.dtype
-    x = x.astype(jnp.float32)
-    mu = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
-    y = (x - mu) * jax.lax.rsqrt(var + eps)
-    return (y * weight.astype(jnp.float32) + bias.astype(jnp.float32)).astype(dt)
-
-
-# ---------------------------------------------------------------------------
-# Feed-forward variants
-# ---------------------------------------------------------------------------
-
-def ffn_init(rng, d_model: int, d_ff: int, act: str, dtype) -> dict:
-    ks = jax.random.split(rng, 3)
-    if act == "swiglu":
-        return {"wi": dense_init(ks[0], (d_model, d_ff), dtype),
-                "wg": dense_init(ks[1], (d_model, d_ff), dtype),
-                "wo": dense_init(ks[2], (d_ff, d_model), dtype)}
-    return {"wi": dense_init(ks[0], (d_model, d_ff), dtype),
-            "wo": dense_init(ks[2], (d_ff, d_model), dtype)}
-
-
-def ffn_apply(params: dict, x: jnp.ndarray, act: str) -> jnp.ndarray:
-    h = x @ params["wi"]
-    if act == "swiglu":
-        h = jax.nn.silu(x @ params["wg"]) * h
-    elif act == "gelu":
-        h = jax.nn.gelu(h)
-    elif act == "relu_sq":
-        h = jnp.square(jax.nn.relu(h))
-    else:
-        raise ValueError(act)
-    return h @ params["wo"]
 
 
 def mlp_tower_init(rng, dims: tuple[int, ...], dtype) -> dict:
@@ -75,30 +31,3 @@ def mlp_tower_apply(params: dict, x: jnp.ndarray, *, final_act: bool = False):
             x = jax.nn.relu(x)
     return x
 
-
-# ---------------------------------------------------------------------------
-# RoPE (incl. the M-RoPE degenerate form for text positions)
-# ---------------------------------------------------------------------------
-
-def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                            / head_dim))
-
-
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float):
-    """x: [..., S, H, hd]; positions: [..., S] int -> rotated x.
-
-    M-RoPE note (qwen2-vl): with text-only/stub-vision inputs all three
-    position sections (t/h/w) carry the same sequential ids, which makes
-    M-RoPE numerically identical to 1-D RoPE; we use the 1-D form and record
-    the simplification in DESIGN.md.
-    """
-    hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # [hd/2]
-    ang = positions[..., None].astype(jnp.float32) * freqs   # [..., S, hd/2]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    cos = cos[..., None, :]                             # broadcast over heads
-    sin = sin[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.astype(x.dtype)

@@ -1,5 +1,4 @@
-from repro.optim.optimizers import (adamw_init, adamw_lowmem_init,
-                                    adamw_lowmem_update, adamw_update,
+from repro.optim.optimizers import (adamw_init, adamw_update,
                                     apply_error_feedback, compress_grads,
                                     rowwise_adagrad_init,
                                     rowwise_adagrad_update, sgdm_init,

@@ -1,19 +1,15 @@
 """Optimizers tuned for the workloads here.
 
 * adamw            — f32 moments + f32 master copy (highest fidelity)
-* adamw_lowmem     — bf16 moments, no master copy (fits 398B on v5e HBM;
-                     the dry-run default for the biggest archs)
 * sgdm             — momentum SGD
 * rowwise_adagrad  — per-row accumulator for embedding tables (DLRM standard;
                      one f32 scalar per row instead of per element)
 
 All are functional: init(params) -> state; update(params, grads, state) ->
-(params, state). Sharding of the state follows the parameter specs
-(launch/steps.param_specs_like).
+(params, state).
 """
 from __future__ import annotations
 
-import functools
 from typing import Any
 
 import jax
@@ -49,40 +45,6 @@ def adamw_update(params, grads, state, *, lr=1e-4, b1=0.9, b2=0.999,
                           is_leaf=lambda x: isinstance(x, tuple))
     params = jax.tree.map(lambda p, w: w.astype(p.dtype), params, master)
     return params, {"m": m, "v": v, "master": master, "count": c}
-
-
-# -- AdamW low-memory ---------------------------------------------------------
-
-def adamw_lowmem_init(params: Any) -> dict:
-    return {
-        "m": jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.bfloat16), params),
-        "v": jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.bfloat16), params),
-        "count": jnp.zeros((), jnp.int32),
-    }
-
-
-def adamw_lowmem_update(params, grads, state, *, lr=1e-4, b1=0.9, b2=0.999,
-                        eps=1e-8, wd=0.0):
-    c = state["count"] + 1
-    cf = c.astype(jnp.float32)
-
-    def upd(p, g, m, v):
-        g = g.astype(jnp.float32)
-        m32 = b1 * m.astype(jnp.float32) + (1 - b1) * g
-        v32 = b2 * v.astype(jnp.float32) + (1 - b2) * jnp.square(g)
-        mh = m32 / (1 - b1 ** cf)
-        vh = v32 / (1 - b2 ** cf)
-        new_p = p.astype(jnp.float32) - lr * (mh / (jnp.sqrt(vh) + eps)
-                                              + wd * p.astype(jnp.float32))
-        return new_p.astype(p.dtype), m32.astype(jnp.bfloat16), \
-            v32.astype(jnp.bfloat16)
-
-    out = jax.tree.map(upd, params, grads, state["m"], state["v"])
-    params = jax.tree.map(lambda t: t[0], out,
-                          is_leaf=lambda x: isinstance(x, tuple))
-    m = jax.tree.map(lambda t: t[1], out, is_leaf=lambda x: isinstance(x, tuple))
-    v = jax.tree.map(lambda t: t[2], out, is_leaf=lambda x: isinstance(x, tuple))
-    return params, {"m": m, "v": v, "count": c}
 
 
 # -- SGD momentum --------------------------------------------------------------

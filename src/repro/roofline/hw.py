@@ -3,7 +3,6 @@
 PEAK_FLOPS_BF16 = 197e12       # FLOP/s per chip
 HBM_BW = 819e9                 # B/s per chip
 ICI_BW_PER_LINK = 50e9         # B/s per link
-HBM_BYTES = 16 * 2**30         # 16 GiB per chip
 VMEM_BYTES = 128 * 2**20
 
 DTYPE_BYTES = {

@@ -3,7 +3,7 @@
 The seed behaviour (every table fits on device), re-homed behind the
 `EmbeddingStorage` protocol. `lookup()` is the jit-traceable dense path.
 For a `[T, R, D]` stack and `[B, T, L]` indices: hot-first remap,
-optional table-stack padding for whole-table sharding, then either a
+optional zero tables appended to the stack, then either a
 vmapped `jnp.take` (XLA baseline) or the Pallas slab-gather kernel's
 (table, batch block) grid, and the shared pooling reduction. Rows or bag
 sizes per table (`EmbeddingStageConfig.per_table`) are drawn as one
@@ -148,14 +148,6 @@ class DeviceStorage(EmbeddingStorage):
                     [w_t, jnp.zeros((cfg.shard_pad_tables, *w_t.shape[1:]),
                                     w_t.dtype)], axis=0)
 
-        # Pin the table-parallel layout end to end: indices reshard to the
-        # table owners (small a2a), gathers stay local, only POOLED outputs
-        # travel back (EXPERIMENTS.md SPerf C1). Lazy import: models.dlrm
-        # imports core.embedding (avoid the package-level cycle).
-        from repro.models import pspec
-        idx_t = pspec.constrain_tablewise(idx_t)
-        if w_t is not None:
-            w_t = pspec.constrain_tablewise(w_t)
         if cfg.backend == "xla" or (cfg.backend == "auto"
                                     and jax.default_backend() != "tpu"):
             rows = jax.vmap(
@@ -166,7 +158,6 @@ class DeviceStorage(EmbeddingStorage):
             pooled = embedding_bag_stacked(tables, idx_t, w_t,
                                            mode=cfg.combine,
                                            opts=cfg.kernel_opts())
-        pooled = pspec.constrain_tablewise(pooled)     # [T(+pad), B, D]
         pooled = jnp.swapaxes(pooled, 0, 1)            # [B, T(+pad), D]
         if cfg.shard_pad_tables:
             pooled = pooled[:, :cfg.num_tables]

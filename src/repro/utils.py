@@ -47,12 +47,6 @@ def asdict_shallow(cfg: Any) -> dict:
     return {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
 
 
-def shard_map_compat(*, mesh, in_specs, out_specs, check_vma=True):
-    """Decorator form of `jax.shard_map`."""
-    return jax.shard_map(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_vma=check_vma)
-
-
 def timeit_median(fn: Callable[[], Any], iters: int = 5, warmup: int = 2) -> float:
     """Median wall-clock seconds of fn() with block_until_ready on jax outputs."""
     def _run() -> float:
@@ -84,29 +78,9 @@ def tree_finite(tree: Any) -> bool:
     return bool(jnp.all(jnp.stack(leaves)))
 
 
-def write_json(path: str, obj: Any) -> None:
-    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    tmp = path + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(obj, f, indent=2, sort_keys=True, default=_json_default)
-    os.replace(tmp, path)  # atomic
-
-
 def read_json(path: str) -> Any:
     with open(path) as f:
         return json.load(f)
-
-
-def _json_default(o: Any) -> Any:
-    if isinstance(o, (np.integer,)):
-        return int(o)
-    if isinstance(o, (np.floating,)):
-        return float(o)
-    if isinstance(o, np.ndarray):
-        return o.tolist()
-    if dataclasses.is_dataclass(o):
-        return dataclasses.asdict(o)
-    return str(o)
 
 
 def human_bytes(n: float) -> str:

@@ -1,7 +1,6 @@
 """Substrate tests: data determinism/resume, checkpoint atomicity+rotation,
 fault-tolerant train loop (restart, preemption, straggler), serving batcher."""
 import os
-import signal
 import time
 
 import numpy as np
@@ -11,31 +10,12 @@ import pytest
 
 from repro.checkpoint import (CheckpointError, CheckpointManager,
                               ModelUpdateStream)
-from repro.data import DLRMQueryStream, TokenStream, HETERO_MIXES
+from repro.data import DLRMQueryStream, HETERO_MIXES
 from repro.runtime import TrainLoop, TrainLoopConfig
 from repro.serving import BatcherConfig, InferenceServer, Query
 
 
 # -- data ---------------------------------------------------------------------
-
-def test_token_stream_deterministic_and_resumable():
-    s1 = TokenStream(vocab_size=100, seq_len=8, global_batch=4, seed=7)
-    batches = [s1.next_batch() for _ in range(5)]
-    s2 = TokenStream(vocab_size=100, seq_len=8, global_batch=4, seed=7)
-    s2.load_state_dict({"seed": 7, "step": 3, "shard": 0})
-    np.testing.assert_array_equal(s2.next_batch()["tokens"],
-                                  batches[3]["tokens"])
-
-
-def test_token_stream_sharding_disjoint_rng():
-    a = TokenStream(vocab_size=1000, seq_len=16, global_batch=8, seed=1,
-                    shard=0, num_shards=2)
-    b = TokenStream(vocab_size=1000, seq_len=16, global_batch=8, seed=1,
-                    shard=1, num_shards=2)
-    assert a.local_batch == b.local_batch == 4
-    assert not np.array_equal(a.next_batch()["tokens"],
-                              b.next_batch()["tokens"])
-
 
 def test_dlrm_stream_hotness_and_mixes():
     s = DLRMQueryStream(num_tables=3, rows=1000, pooling=5, batch_size=4,
