@@ -205,9 +205,10 @@ class ParameterServer:
 
     def hint_valid(self, n: int) -> None:
         """Mark only the first `n` queries of the NEXT lookup as real
-        traffic. The serving batcher pads partial batches to max_batch with
-        zero queries for shape stability; without this hint those fabricated
-        row-0 accesses would inflate hit rates and skew refresh planning."""
+        traffic. The serving batcher pads partial batches to a batch shape
+        with zero queries for shape stability; without this hint those
+        fabricated row-0 accesses would inflate hit rates and skew refresh
+        planning."""
         self._valid_hint = int(n)
 
     def lookup(self, indices: np.ndarray) -> np.ndarray:

@@ -64,7 +64,6 @@ class Query:
 class BatcherConfig:
     max_batch: int = 2048
     max_wait_s: float = 0.002   # SLA-driven batching window
-    pad_to_max: bool = True     # stable shapes => no recompilation
     # admission control (overload shedding); both default OFF so steady
     # state is untouched:
     # hard bound on queued queries — submit() sheds (typed rejection)
@@ -190,6 +189,28 @@ class Batcher:
         self.in_flight -= n
 
 
+#: Halvings of `max_batch` a handed-out batch may be padded to: the
+#: batch shapes run from `max_batch` down to `max_batch // 8`.
+SHAPE_HALVINGS = 3
+
+
+def batch_shapes(max_batch: int, min_batch: int = 0) -> tuple[int, ...]:
+    """The batch sizes the engine runs, largest first, each compiled once
+    in set-up: `max_batch` and its halvings down to
+    `max_batch >> SHAPE_HALVINGS` (at least 1), and where an SLO shrink
+    rung is armed (`min_batch > 0`) every `max_batch` the rung can set,
+    its halvings floored at `min_batch` (`SLOController._shrink`).
+    `InferenceServer._hand_out` pads a batch to the smallest that holds
+    it, so a partial batch of an open loop does not pay the kernel and
+    the copy for a full batch of zero rows."""
+    sizes = {max(1, max_batch >> k) for k in range(SHAPE_HALVINGS + 1)}
+    b = max_batch
+    while 0 < min_batch < b:
+        b = max(min_batch, b // 2)
+        sizes.add(b)
+    return tuple(sorted(sizes, reverse=True))
+
+
 def _device_beside_host() -> bool:
     """Whether JAX's default device is an accelerator of its own. On the
     CPU backend the engine computes on the host's own cores, the ones the
@@ -288,7 +309,8 @@ class InferenceServer:
                  sla_ms: float = 50.0, storage=None,
                  refresh_every_batches: int = 0,
                  async_refresh: bool = False,
-                 clock: Optional[Callable] = None):
+                 clock: Optional[Callable] = None,
+                 min_batch: int = 0):
         self.forward = forward
         # `clock` abstracts serving time: None = real time.perf_counter;
         # a replay harness passes a `repro.traffic.VirtualClock` (callable
@@ -297,6 +319,10 @@ class InferenceServer:
         self.clock = clock if clock is not None else time.perf_counter
         self._clock_advance = getattr(clock, "advance", None)
         self.batcher = Batcher(batcher_cfg, clock=self.clock)
+        # the batch sizes a handed-out batch is padded to, the SLO shrink
+        # rung's among them when one is armed down to `min_batch`
+        # (`batch_shapes`); a session compiles each of them in set-up
+        self.shapes = batch_shapes(batcher_cfg.max_batch, min_batch)
         self.sla_s = sla_ms / 1e3
         self.stats = ServeStats()
         self.storage = storage
@@ -460,9 +486,13 @@ class InferenceServer:
         batch = self.batcher.next_batch(force=force)
         if not batch:
             return None
-        cfg = self.batcher.cfg
-        return _Flight(batch, cfg.max_batch if cfg.pad_to_max
-                       else len(batch), self.clock())
+        # the smallest shape that holds the batch; never past the live
+        # max_batch, so a full batch runs at max_batch, the size
+        # `_stage_next` stages it at
+        limit = self.batcher.cfg.max_batch
+        padded = min((s for s in self.shapes if len(batch) <= s <= limit),
+                     default=limit)
+        return _Flight(batch, padded, self.clock())
 
     def _prepare(self, flight: _Flight):
         """Assemble a batch about to be dispatched and run the storage

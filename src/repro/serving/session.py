@@ -115,21 +115,16 @@ class ServingSession:
             self._build_engine(caps), batcher, sla_ms=sla_ms,
             storage=self.storage,
             refresh_every_batches=refresh_every_batches,
-            async_refresh=async_refresh, clock=clock)
+            async_refresh=async_refresh, clock=clock,
+            # the shrink rung re-sizes the batch quantum mid-overload; its
+            # sizes join the batch shapes warmed below, so engaging the
+            # ladder never stalls a breached window on XLA compilation
+            min_batch=slo.min_batch if slo is not None else 0)
         self._forward = self.server.forward
         self._closed = False
         self._next_qid = 0
         if warmup:
-            sizes = [batcher.max_batch]
-            if slo is not None and slo.min_batch > 0:
-                # the shrink rung re-sizes the batch quantum mid-overload;
-                # pre-compile every rung shape now so engaging the ladder
-                # never stalls a breached window on XLA compilation
-                b = batcher.max_batch
-                while b > slo.min_batch:
-                    b = max(slo.min_batch, b // 2)
-                    sizes.append(b)
-            self._warmup(sizes)
+            self._warmup()
         # runtime auto-tuning (queue depth / tier capacity): driven from
         # poll() through protocol verbs only. Backends that do not report
         # `tunable` (device) leave the tuner permanently inert — asking for
@@ -191,13 +186,14 @@ class ServingSession:
             return rest(jnp.asarray(dense), pooled)  # jitted remainder
         return forward
 
-    def _warmup(self, batch_sizes) -> None:
-        """Compile the engine on a zero batch per armed batch size, then
-        drop the synthetic traffic's footprint (warm-cache entries,
-        refresh-window batch) and its counters so measurements start
-        clean."""
+    def _warmup(self) -> None:
+        """Compile the engine on a zero batch per batch shape the server
+        pads to (`InferenceServer.shapes`), so that no batch compiles
+        while serving, then drop the synthetic traffic's footprint
+        (warm-cache entries, refresh-window batch) and its counters so
+        measurements start clean."""
         cfg = self.model.cfg
-        for batch in batch_sizes:
+        for batch in self.server.shapes:
             dense = np.zeros((batch, cfg.dense_features), np.float32)
             idx = np.zeros((batch, *cfg.embedding.query_index_shape()),
                            np.int32)

@@ -281,8 +281,8 @@ def test_bf16_control_breaks_the_tolerance(served_and_reference):
 
 
 def test_warmup_and_put_follow_the_flat_layout(monkeypatch):
-    """The zero batch of `_warmup` is `[B, sum(L)]`, and `serve.put`
-    counts the row ids it copies, padding included."""
+    """The zero batch of `_warmup` is `[b, sum(L)]` for each batch shape
+    b, and `serve.put` counts the row ids it copies, padding included."""
     seen = []
 
     class Recorder:
@@ -300,7 +300,8 @@ def test_warmup_and_put_follow_the_flat_layout(monkeypatch):
     sess = ServingSession(model, model.init(jax.random.PRNGKey(1)),
                           batcher=BatcherConfig(max_batch=8))
     puts = [s for name, s in seen if name == "serve.put"]
-    assert puts == [{"bytes": 8 * 13 * 4 + 8 * 22 * 4, "lookups": 8 * 22}]
+    assert puts == [{"bytes": b * 13 * 4 + b * 22 * 4, "lookups": b * 22}
+                    for b in (8, 4, 2, 1)]
     sess.close()
 
 

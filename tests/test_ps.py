@@ -262,13 +262,15 @@ def test_batcher_drain_force_flushes_partial_batch():
 
 def test_padded_partial_batch_not_counted_as_traffic():
     """Batcher zero-padding must not inflate PS stats or the refresh
-    window (the padded rows still get served values — shape stability)."""
+    window (the padded rows still get served values — shape stability).
+    Three queries pad to 4, the smallest of max_batch 8's shapes that
+    holds them."""
     pats = _med_pats()
     ps = ParameterServer(_tables(), PSConfig(hot_rows=16, warm_slots=16))
 
     def fwd(dense, idx):
         rows = ps.lookup(idx)
-        assert rows.shape == (8, TABLES, POOL, DIM)   # padded shape served
+        assert rows.shape == (4, TABLES, POOL, DIM)   # padded shape served
         return np.zeros(len(dense), np.float32)
 
     from repro.storage.tiered import TieredStorage
@@ -281,7 +283,7 @@ def test_padded_partial_batch_not_counted_as_traffic():
     srv.drain(timeout_s=1.0)
     assert srv.stats.served == 3
     st = ps.stats()
-    assert st["total_accesses"] == 3 * TABLES * POOL   # not 8 * T * L
+    assert st["total_accesses"] == 3 * TABLES * POOL   # not 4 * T * L
     assert ps.window[-1].shape[0] == 3                 # window holds real n
     assert (st["hot_hits"] + st["warm_hits"] + st["cold_misses"]
             == st["total_accesses"])
